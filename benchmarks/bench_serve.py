@@ -40,7 +40,6 @@ import time
 from typing import List, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import analysis
@@ -88,35 +87,6 @@ def _build(bc: BenchConfig):
     m = M.build(cfg)
     values, _ = sh.split_tree(m.init(jax.random.PRNGKey(0)))
     return cfg, m, values
-
-
-def _reference_tokens(m, values, requests, bc: BenchConfig):
-    """Manual per-request decode loop — the serving engine's channel-free
-    tokens must match this bit for bit (continuous batching and the fused
-    tick must not perturb the decode).  The step functions are jitted once
-    (an eager ``decode_step`` re-traces its inner scan every call, which
-    accumulates a fresh compiled program per decode step)."""
-    prefill = jax.jit(
-        lambda v, t: m.prefill(v, {"tokens": t}, max_seq=bc.max_seq))
-    decode = jax.jit(m.decode_step)
-    out = {}
-    for req in requests:
-        tokens = jnp.asarray(req.prompt, jnp.int32)[None]
-        logits, cache = prefill(values, tokens)
-        tok = int(jnp.argmax(logits, -1)[0])
-        toks = [tok]
-        pos = len(req.prompt)
-        budget = req.max_new_tokens - 1
-        while tok != -1 and budget > 0 and pos < bc.max_seq - 1:
-            logits, cache = decode(
-                values, jnp.asarray([[tok]], jnp.int32),
-                jnp.asarray([pos], jnp.int32), cache)
-            tok = int(jnp.argmax(logits, -1)[0])
-            toks.append(tok)
-            pos += 1
-            budget -= 1
-        out[req.rid] = toks
-    return out
 
 
 def _serve_point(engine: ServeEngine, requests, protocol, clock):
@@ -177,7 +147,7 @@ def run(smoke: bool = False,
     # -- channel-free baseline + bitwise reference check -------------------
     free_outs, free_stats = _serve_point(engine, requests, None, clock)
     _check_dispatch("free", free_outs, free_stats, bc.batch_slots)
-    ref = _reference_tokens(m, values, requests, bc)
+    ref = se.reference_tokens(m, values, requests, bc.max_seq, eos_id=-1)
     for rid, toks in ref.items():
         if free_outs[rid].tokens != toks:
             raise RuntimeError(

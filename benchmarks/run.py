@@ -29,6 +29,8 @@ REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     fast = "--fast" in sys.argv
     from benchmarks import (bench_comm, bench_contention, bench_curves,
                             bench_fig2, bench_kernels, bench_roofline,

@@ -19,7 +19,7 @@ point declares):
     hazard reported with the trace error attached.
 
 ``f64-promotion``
-    The entry point is re-traced under ``jax.experimental.enable_x64`` and
+    The entry point is re-traced under ``jax.enable_x64`` and
     the jaxpr is walked for float64/complex128 *array* avals (scalar weak-
     type f64 intermediates are JAX-internal promotion noise and stay
     legal) and for ``convert_element_type`` ops landing on f64 arrays.
@@ -225,7 +225,7 @@ def check_no_f64(name: str, fn: Callable,
     an f64 array the moment someone runs with ``JAX_ENABLE_X64=1``.
     """
     where = f"contract:{name}"
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     args = argsf(0.05)
     try:
         with enable_x64():

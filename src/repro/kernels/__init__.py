@@ -3,11 +3,11 @@ ocs_contention).  Each package is <name>.py (the kernel) + ops.py (jit'd
 differentiable wrapper) + ref.py (pure-jnp oracle the parity suite compares
 against, see tests/kernel_parity.py).
 
-Interpret-mode policy: the kernels are written for TPU but every CI container
-is CPU-only, so the wrappers run them through the Pallas interpreter there.
-Historically each ops.py hardcoded ``INTERPRET = True`` at import time, which
-silently interpreted on real TPUs too; :func:`interpret_default` replaces
-that with one env-driven resolution shared by all kernel wrappers.
+Interpret-mode policy: the kernels are written for the TPU.  On a TPU
+backend they always compile (:func:`interpret_default` returns ``False``,
+and asking for the interpreter there is an error, so a chip run can never
+silently measure the interpreter).  On any other backend -- the CPU test
+containers -- they run through the Pallas interpreter.
 """
 
 from __future__ import annotations
@@ -35,24 +35,27 @@ _FALSE = ("0", "false", "no", "off")
 def interpret_default() -> bool:
     """Should Pallas kernels run in interpreter mode by default?
 
-    Resolution order:
-      1. ``REPRO_PALLAS_INTERPRET`` env var (``1/true/yes/on`` or
-         ``0/false/no/off``) — explicit operator override, read on every
-         resolution (eager calls and each fresh jit trace; a value already
-         baked into a cached jit executable persists until retrace);
-      2. otherwise: interpret unless JAX is actually running on a TPU
-         backend (so real-TPU runs compile the kernels instead of silently
-         interpreting, and CPU/GPU hosts keep working out of the box).
+    On a TPU backend: never.  The kernels compile, and
+    ``REPRO_PALLAS_INTERPRET`` set to a true value raises instead of
+    switching to the interpreter.
+
+    Elsewhere: ``REPRO_PALLAS_INTERPRET`` (``1/true/yes/on`` or
+    ``0/false/no/off``) overrides, read on every resolution (eager calls and
+    each fresh jit trace; a value already baked into a cached jit executable
+    persists until retrace); unset, the kernels interpret.
     """
-    env = os.environ.get(_ENV_VAR)
-    if env is not None:
-        val = env.strip().lower()
-        if val in _TRUE:
-            return True
-        if val in _FALSE:
-            return False
-        raise ValueError(
-            f"{_ENV_VAR}={env!r}: expected one of {_TRUE + _FALSE}")
     import jax
 
-    return jax.default_backend() != "tpu"
+    on_tpu = jax.default_backend() == "tpu"
+    env = os.environ.get(_ENV_VAR)
+    if env is None:
+        return not on_tpu
+    val = env.strip().lower()
+    if val not in _TRUE + _FALSE:
+        raise ValueError(
+            f"{_ENV_VAR}={env!r}: expected one of {_TRUE + _FALSE}")
+    if on_tpu and val in _TRUE:
+        raise RuntimeError(
+            f"{_ENV_VAR}={env!r} on a TPU backend: the kernels compile on "
+            "the chip and never fall back to the interpreter there")
+    return val in _TRUE

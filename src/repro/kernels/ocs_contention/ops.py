@@ -45,7 +45,7 @@ def draw_heard_packed(rng: jax.Array, p_keep: jax.Array, n: int, k: int, *,
 def contend(word: jax.Array, heard: jax.Array, mask: jax.Array,
             total_bits: jax.Array, *, n_slots: int, max_rounds: int,
             block_k: int = 1024, interpret: bool | None = None):
-    """Kernel dispatch + cross-tile reduction of the accounting partials.
+    """Kernel dispatch + reduction of the per-element accounting planes.
 
     Returns (winner (K,) int32, contending (max_rounds,) int32, collided
     (max_rounds,) int32) — the same contract as ``ref.contend``.
@@ -54,7 +54,8 @@ def contend(word: jax.Array, heard: jax.Array, mask: jax.Array,
     winner, cont, coll = K.contend(
         word, heard, mask, total_bits, n_slots=n_slots,
         max_rounds=max_rounds, block_k=block_k, interpret=interpret)
-    return winner, jnp.sum(cont, axis=0), jnp.sum(coll, axis=0)
+    return (winner, jnp.sum(cont, axis=1, dtype=jnp.int32),
+            jnp.sum(coll, axis=1, dtype=jnp.int32))
 
 
 def noisy_contention(word: jax.Array, mask: jax.Array,

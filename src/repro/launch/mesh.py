@@ -9,32 +9,18 @@ practice; the dry-run proves the program shards over it).
 
 from __future__ import annotations
 
-from typing import Optional
-
-import jax
-
 from repro.parallel import sharding as sh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axes)
+    return sh.make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2):
     """Small host-device mesh for subprocess distribution tests."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh((data, model), ("data", "model"),
-                             axis_types=(AxisType.Auto,) * 2)
-    except (ImportError, TypeError):
-        return jax.make_mesh((data, model), ("data", "model"))
+    return sh.make_mesh((data, model), ("data", "model"))
 
 
 def rules_for(shape_name: str, global_batch: int, mesh) -> dict:

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.checkpoint import checkpointer
 from repro.configs import ARCH_IDS, get_config, get_reduced
+from repro.launch.cache import use_compile_cache
 from repro.models import model as M
 from repro.parallel import sharding as sh
 from repro.protocol import Protocol
@@ -42,7 +43,7 @@ def _build_protocol(args, n_workers: int):
                         max_rounds=args.max_rounds, backend=args.backend)
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true")
@@ -71,8 +72,11 @@ def main():
                     help="Poisson arrival rate (requests per decode tick)")
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build(args):
+    """The served stack of ``args``: (model, engine, request stream)."""
     get = get_reduced if args.smoke else get_config
     cfg = get(args.arch)
     m = M.build(cfg)
@@ -93,6 +97,14 @@ def main():
     reqs = poisson_requests(args.requests, args.rate, cfg.vocab_size,
                             prompt_len=args.prompt_len,
                             max_new_tokens=args.max_new, seed=args.seed)
+    return m, engine, reqs
+
+
+def main(argv=None):
+    use_compile_cache()
+    args = parse_args(argv)
+    _m, engine, reqs = build(args)
+    clock = engine.config.clock
     outs = engine.run(reqs)
     for rid in sorted(outs):
         c = outs[rid]

@@ -22,6 +22,7 @@ import jax
 
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.data import pipeline
+from repro.launch.cache import use_compile_cache
 from repro.models import model as M
 from repro.optim import optimizers, schedules
 from repro.parallel import sharding as sh
@@ -30,6 +31,7 @@ from repro.train.trainer import TrainerConfig
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true",

@@ -14,12 +14,13 @@ the matching 1F1B-equivalent backward (bubbles included) for training.
 
 from __future__ import annotations
 
-import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from repro.parallel import sharding as sh
 
 
 def gpipe(stage_fn: Callable, mesh, axis: str = "stage"):
@@ -71,14 +72,8 @@ def gpipe(stage_fn: Callable, mesh, axis: str = "stage"):
         params_local = jax.tree.map(lambda p: p[0], params)
         return pipelined(params_local, x_micro)
 
-    kwargs = dict(mesh=mesh, in_specs=(P(axis), P()), out_specs=P())
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:            # jax<0.6: experimental namespace,
-        from jax.experimental.shard_map import shard_map
-        kwargs["check_rep"] = False  # replication check kwarg predates
-    else:                            # its rename to check_vma
-        kwargs["check_vma"] = False
-    return shard_map(stage_local, **kwargs)
+    return sh.shard_map(stage_local, mesh, in_specs=(P(axis), P()),
+                        out_specs=P())
 
 
 def sequential_reference(stage_fn: Callable, stage_params, x_micro):

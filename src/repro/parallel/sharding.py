@@ -10,13 +10,12 @@ context has been installed (so single-device smoke tests run untouched code).
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import threading
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # logical axis -> mesh axis (or tuple of mesh axes). Axes absent from the
 # active mesh are dropped at resolution time, so one rule table serves the
@@ -89,6 +88,24 @@ def split_tree(tree):
     values = jax.tree.map(lambda t: t.value, tree, is_leaf=_is_tagged)
     axes = jax.tree.map(lambda t: t.axes, tree, is_leaf=_is_tagged)
     return values, axes
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """Device mesh over the first ``prod(shape)`` devices with ``Auto`` axes.
+
+    JAX 0.9 builds ``Explicit`` axes by default, under which an eager or
+    un-meshed ``shard_map`` refuses arguments placed on one device; every
+    mesh of this repo is an ``Auto`` mesh so GSPMD places them.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def shard_map(fn, mesh: Mesh, in_specs, out_specs):
+    """The repo's one ``jax.shard_map`` call site (replication check off:
+    the callers psum/all_gather explicitly)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def mesh_axis_sizes(mesh: Mesh) -> dict:

@@ -387,6 +387,37 @@ class ServeEngine:
         return self.outputs
 
 
+def reference_tokens(model, values, requests: List[Request], max_seq: int,
+                     eos_id: int = -1) -> Dict[int, List[int]]:
+    """Channel-free greedy decode, one request at a time: a batch-1 jitted
+    prefill, then batch-1 jitted decode steps under the engine's stopping
+    rule (EOS, token budget, ``max_seq - 1``).  Channel-free serving must
+    reproduce it; continuous batching and the fused tick must not perturb
+    the decode.  (An eager ``decode_step`` would re-trace its inner scan
+    every call, hence the jits.)"""
+    prefill = jax.jit(
+        lambda v, t: model.prefill(v, {"tokens": t}, max_seq=max_seq))
+    decode = jax.jit(model.decode_step)
+    out = {}
+    for req in requests:
+        tokens = jnp.asarray(req.prompt, jnp.int32)[None]
+        logits, cache = prefill(values, tokens)
+        tok = int(jnp.argmax(logits, -1)[0])
+        toks = [tok]
+        pos = len(req.prompt)
+        budget = req.max_new_tokens - 1
+        while tok != eos_id and budget > 0 and pos < max_seq - 1:
+            logits, cache = decode(
+                values, jnp.asarray([[tok]], jnp.int32),
+                jnp.asarray([pos], jnp.int32), cache)
+            tok = int(jnp.argmax(logits, -1)[0])
+            toks.append(tok)
+            pos += 1
+            budget -= 1
+        out[req.rid] = toks
+    return out
+
+
 def _batch_axis(batch_shape, one_shape, b: int) -> int:
     for i, (bs, os) in enumerate(zip(batch_shape, one_shape)):
         if bs == b and os == 1:

@@ -3,10 +3,10 @@
 Both grid runners shard one leading "lane" axis over the host's local
 devices: ``repro.sim.sweep`` shards the *scenario* axis, and
 ``repro.sim.train_curves`` shards the *p_miss lane* axis of the fused curve
-engine.  The mesh construction and the jax-version shims (``jax.shard_map``
-vs ``jax.experimental.shard_map``, ``check_vma`` vs ``check_rep``) live here
-so every runner gets the identical placement semantics — and the identical
-bit-for-bit-vs-vmap property that ``tests/test_sweep.py`` and
+engine.  The lane meshes (``Auto`` axes, built by
+``repro.parallel.sharding.make_mesh``) and the ``shard_map`` wrappers live
+here so every runner gets the identical placement semantics — and the
+identical bit-for-bit-vs-vmap property that ``tests/test_sweep.py`` and
 ``tests/test_train_curves.py`` assert with forced host devices.
 
 Sharding only changes placement, never results: callers pad the lane axis up
@@ -22,41 +22,20 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P  # noqa: F401  (re-exported)
 
+from repro.parallel import sharding as sh
+
 
 @functools.lru_cache(maxsize=None)
 def mesh_1d(n_devices: int, axis: str = "s"):
     """1-D device mesh for a lane axis (cached: jit keys on identity)."""
-    make_mesh = getattr(jax, "make_mesh", None)
-    if make_mesh is not None:
-        return make_mesh((n_devices,), (axis,))
-    # jax<0.4.35 (pyproject floor is 0.4.30): build the Mesh directly
-    return jax.sharding.Mesh(
-        np.asarray(jax.devices()[:n_devices]), (axis,))
+    return sh.make_mesh((n_devices,), (axis,))
 
 
 @functools.lru_cache(maxsize=None)
 def mesh_2d(n_s: int, n_d: int, axes=("s", "d")):
     """2-D device mesh: ``axes[0]`` lanes x ``axes[1]`` data-parallel ranks
     (cached: jit keys on mesh identity)."""
-    make_mesh = getattr(jax, "make_mesh", None)
-    if make_mesh is not None:
-        return make_mesh((n_s, n_d), tuple(axes))
-    return jax.sharding.Mesh(
-        np.asarray(jax.devices()[:n_s * n_d]).reshape(n_s, n_d),
-        tuple(axes))
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """The jax-version shard_map shim shared by :func:`shard_1d`/:func:`shard_2d`."""
-    shard_map = getattr(jax, "shard_map", None)
-    kwargs = {}
-    if shard_map is None:            # jax<0.6: experimental namespace,
-        from jax.experimental.shard_map import shard_map
-        kwargs["check_rep"] = False  # replication check kwarg predates
-    else:                            # its rename to check_vma
-        kwargs["check_vma"] = False
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kwargs)
+    return sh.make_mesh((n_s, n_d), tuple(axes))
 
 
 def shard_1d(fn, n_devices: int, in_specs, out_specs, axis: str = "s"):
@@ -66,7 +45,7 @@ def shard_1d(fn, n_devices: int, in_specs, out_specs, axis: str = "s"):
     prefixes of the arguments/results); pass ``P(axis)`` for lane-leading
     arguments and ``P()`` for replicated ones.
     """
-    return _shard_map(fn, mesh_1d(n_devices, axis), in_specs, out_specs)
+    return sh.shard_map(fn, mesh_1d(n_devices, axis), in_specs, out_specs)
 
 
 def shard_2d(fn, n_s: int, n_d: int, in_specs, out_specs, axes=("s", "d")):
@@ -76,8 +55,8 @@ def shard_2d(fn, n_s: int, n_d: int, in_specs, out_specs, axes=("s", "d")):
     (``lax.all_gather``/``lax.psum``), which is how the 2-D curve engine
     all-reduces compressed gradients inside the fused scan.
     """
-    return _shard_map(fn, mesh_2d(n_s, n_d, tuple(axes)), in_specs,
-                      out_specs)
+    return sh.shard_map(fn, mesh_2d(n_s, n_d, tuple(axes)), in_specs,
+                        out_specs)
 
 
 def dp_mesh_shape(n_devices, n_lanes: int, dp_shards: int):

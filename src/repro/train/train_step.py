@@ -73,8 +73,7 @@ def make_train_step(loss_fn: Callable, optimizer, microbatches: int = 1,
 
         def is_key_like(r):
             dtype = jnp.asarray(r).dtype
-            prng_key = getattr(jax.dtypes, "prng_key", None)
-            if prng_key is not None and jnp.issubdtype(dtype, prng_key):
+            if jnp.issubdtype(dtype, jax.dtypes.prng_key):
                 return True                   # new-style typed PRNG keys
             return jnp.issubdtype(dtype, jnp.integer)   # legacy uint32 keys
 

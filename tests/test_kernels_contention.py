@@ -71,10 +71,19 @@ def test_contend_parity_fast():
 
 def test_contend_parity_masked_and_padded_slots():
     # padded workers (mask) + padded scan bound (total_bits < n_slots) +
-    # a block size that forces multiple tiles (cross-tile accounting)
-    _check_contend(grid(n=[8], n_real=[5], k=[128], n_slots=[16],
+    # a block size that forces multiple tiles (cross-tile accounting) with
+    # K padded up to a whole last tile
+    _check_contend(grid(n=[8], n_real=[5], k=[320], n_slots=[16],
                         total_bits=[14], max_rounds=[2], p_miss=[0.15],
-                        seed=[0, 3], block_k=[32]))
+                        seed=[0, 3], block_k=[128]))
+
+
+def test_contend_rejects_unaligned_block():
+    word = jnp.zeros((4, 256), jnp.uint32)
+    heard = jnp.zeros((1, 4, 256), jnp.uint32)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        O.contend(word, heard, jnp.ones((4,), bool), jnp.int32(8),
+                  n_slots=8, max_rounds=1, block_k=96)
 
 
 @pytest.mark.slow

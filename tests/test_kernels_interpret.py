@@ -1,9 +1,9 @@
-"""Interpret-mode resolution: env-driven, no import-time hardcoding.
+"""Interpret-mode resolution: env-driven off the TPU, never on it.
 
-The kernel wrappers historically pinned ``INTERPRET = True`` at import time,
-which silently interpreted on real TPUs; ``repro.kernels.interpret_default``
-resolves per call from ``REPRO_PALLAS_INTERPRET`` (operator override) or the
-active JAX backend.
+``repro.kernels.interpret_default`` resolves per call: on a TPU backend the
+kernels always compile and ``REPRO_PALLAS_INTERPRET=1`` is an error; on any
+other backend the env var overrides and the default is the interpreter.
+The TPU branch is driven here by patching ``jax.default_backend``.
 """
 
 import jax.numpy as jnp
@@ -24,6 +24,24 @@ def test_env_override_resolves_both_settings(monkeypatch, value, expect):
     assert kernels.interpret_default() is expect
 
 
+@pytest.mark.parametrize("value,expect", [
+    (None, False), ("0", False), ("off", False),
+    ("1", RuntimeError), ("true", RuntimeError), ("maybe", ValueError),
+])
+def test_tpu_backend_never_interprets(monkeypatch, value, expect):
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if value is None:
+        monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", value)
+    if expect is False:
+        assert kernels.interpret_default() is False
+    else:
+        with pytest.raises(expect):
+            kernels.interpret_default()
+
+
 def test_invalid_env_value_raises(monkeypatch):
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "maybe")
     with pytest.raises(ValueError):
@@ -31,8 +49,8 @@ def test_invalid_env_value_raises(monkeypatch):
 
 
 def test_default_follows_backend(monkeypatch):
-    """Without the env var, CPU/GPU hosts interpret; a TPU backend would
-    compile (asserted via the same code path the wrappers call)."""
+    """Without the env var, CPU/GPU hosts interpret and a TPU backend
+    compiles (asserted via the same code path the wrappers call)."""
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
     import jax
     assert kernels.interpret_default() is (jax.default_backend() != "tpu")

@@ -23,8 +23,9 @@ def test_gpipe_matches_sequential():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.parallel.pipeline import gpipe, sequential_reference
+        from repro.parallel.sharding import make_mesh
 
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh((4,), ("stage",))
         rng = np.random.default_rng(0)
         d = 16
         params = {"w": jnp.asarray(rng.standard_normal((4, d, d)) * 0.3,
@@ -50,8 +51,9 @@ def test_gpipe_differentiable():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.parallel.pipeline import gpipe, sequential_reference
+        from repro.parallel.sharding import make_mesh
 
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh((4,), ("stage",))
         rng = np.random.default_rng(1)
         d = 8
         params = {"w": jnp.asarray(rng.standard_normal((4, d, d)) * 0.3,
