@@ -349,8 +349,9 @@ def ocs_maxpool_noisy_core(h: jax.Array, mask: jax.Array, id_bits: jax.Array,
                 bit = (word >> shift) & jnp.uint32(1)
                 tx = alive & (bit == 1) & active
                 any_tx = jnp.any(tx, axis=0, keepdims=True)
-                heard = sensing_heard(
-                    jax.random.fold_in(key, d), p_keep, n_max, k_elems)
+                with jax.named_scope("ocs.sense"):
+                    heard = sensing_heard(
+                        jax.random.fold_in(key, d), p_keep, n_max, k_elems)
                 # a sensing worker quits only if someone transmitted AND it
                 # heard
                 alive = alive & (tx | ~(any_tx & heard))

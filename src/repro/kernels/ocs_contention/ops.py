@@ -30,16 +30,17 @@ def draw_heard_packed(rng: jax.Array, p_keep: jax.Array, n: int, k: int, *,
     both backends).  Returns (max_rounds, N, K) uint32 where bit
     ``n_slots - 1 - d`` of ``[r, n, k]`` is sub-slot d's draw.
     """
-    r_keys = jax.vmap(lambda r: jax.random.fold_in(rng, r))(
-        jnp.arange(max_rounds))
-    rd_keys = jax.vmap(lambda kr: jax.vmap(
-        lambda d: jax.random.fold_in(kr, d))(jnp.arange(n_slots)))(r_keys)
-    heard = jax.vmap(jax.vmap(
-        lambda key: ocs.sensing_heard(key, p_keep, n, k)))(rd_keys)
-    plane = jnp.uint32(1) << (jnp.uint32(n_slots - 1)
-                              - jnp.arange(n_slots, dtype=jnp.uint32))
-    return jnp.sum(jnp.where(heard, plane[None, :, None, None],
-                             jnp.uint32(0)), axis=1, dtype=jnp.uint32)
+    with jax.named_scope("ocs.sense"):
+        r_keys = jax.vmap(lambda r: jax.random.fold_in(rng, r))(
+            jnp.arange(max_rounds))
+        rd_keys = jax.vmap(lambda kr: jax.vmap(
+            lambda d: jax.random.fold_in(kr, d))(jnp.arange(n_slots)))(r_keys)
+        heard = jax.vmap(jax.vmap(
+            lambda key: ocs.sensing_heard(key, p_keep, n, k)))(rd_keys)
+        plane = jnp.uint32(1) << (jnp.uint32(n_slots - 1)
+                                  - jnp.arange(n_slots, dtype=jnp.uint32))
+        return jnp.sum(jnp.where(heard, plane[None, :, None, None],
+                                 jnp.uint32(0)), axis=1, dtype=jnp.uint32)
 
 
 def contend(word: jax.Array, heard: jax.Array, mask: jax.Array,

@@ -289,7 +289,14 @@ class Protocol:
         ``kind="ocs"`` additionally needs ``rng`` (the per-sub-slot sensing
         key) and a bound ``p_miss``; both are ordinary traced values, so one
         compiled computation serves a whole miss-probability axis.
+        Every operation it adds to a program sits under the
+        ``protocol.aggregate`` name scope.
         """
+        with jax.named_scope("protocol.aggregate"):
+            return self._aggregate(h, rng)
+
+    def _aggregate(self, h: jax.Array, rng: Optional[jax.Array]
+                   ) -> Tuple[jax.Array, ProtocolAccounting]:
         if self.kind == "sum":
             return jnp.sum(h, axis=0), ProtocolAccounting.zeros()
         if self.kind == "max":

@@ -22,39 +22,52 @@ its end-to-end latency decomposed into compute ticks vs channel slots.
 Dispatch/trace counters mirror ``repro.sim.train_curves``:
 ``dispatch_counts()["tick"]`` counts host->device decode-tick dispatches
 (exactly one per tick — self-checked by ``benchmarks/bench_serve.py``) and
-``trace_counts()["tick"]`` counts compilations of the fused tick.
+``trace_counts()["tick"]`` counts compilations of the fused tick; both are
+views of the :mod:`repro.obs` registry.
+
+The host loop is spanned (:func:`repro.obs.span`): ``serve.admit`` around
+each admission (``rid``, ``slot``), ``serve.tick`` from a tick's dispatch
+to the end of its per-slot bookkeeping (``tick``), and ``serve.sync``
+around every device->host read the loop makes (``what``: ``tokens``,
+``positions``, ``airtime``, ``flags`` or ``first_token``).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import faults
+from repro import faults, obs
 from repro.protocol import Protocol
 
-_TRACE_COUNTS = {"tick": 0}
-_DISPATCH_COUNTS = {"tick": 0}
+_TRACE = "serve.trace."
+_DISPATCH = "serve.dispatch."
 
 
 def trace_counts() -> Dict[str, int]:
-    return dict(_TRACE_COUNTS)
+    return obs.view(_TRACE, ("tick",))
 
 
 def dispatch_counts() -> Dict[str, int]:
-    return dict(_DISPATCH_COUNTS)
+    return obs.view(_DISPATCH, ("tick",))
 
 
 def reset_trace_counts() -> None:
-    _TRACE_COUNTS["tick"] = 0
+    obs.reset(_TRACE)
 
 
 def reset_dispatch_counts() -> None:
-    _DISPATCH_COUNTS["tick"] = 0
+    obs.reset(_DISPATCH)
+
+
+def _sync(what: str, read: Callable[[], Any]) -> Any:
+    """A device->host read of the serving loop, in a ``serve.sync`` span."""
+    with obs.span("serve.sync", what=what):
+        return read()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,6 +151,11 @@ class Completion:
     outage ticks (every worker offline — the degrade policy substituted a
     filler instead of wedging the FIFO), and ``retry_ticks`` counts ticks
     the whole batch stalled re-contending under the ``retry`` policy.
+
+    ``token_ticks`` holds, for each token, the engine tick that delivered
+    it: the admitting tick for the first (the prefill's), then the decode
+    tick of each further token.  With the ``serve.tick`` and
+    ``serve.admit`` spans it gives each token's delivery time.
     """
 
     rid: int
@@ -148,6 +166,7 @@ class Completion:
     uplink_bits: int = 0
     degraded_tokens: int = 0
     retry_ticks: int = 0
+    token_ticks: List[int] = dataclasses.field(default_factory=list)
 
     def latency_us(self, clock: ChannelClock) -> float:
         return clock.latency_us(self.latency_ticks, self.channel_slots)
@@ -187,7 +206,7 @@ class ServeEngine:
 
         def _tick(v, protocol, fault, fstate, cur_token, positions, cache,
                   tick):
-            _TRACE_COUNTS["tick"] += 1
+            obs.count(_TRACE + "tick")
             if protocol is None:
                 logits, new_cache = model.decode_step(v, cur_token,
                                                       positions, cache)
@@ -297,7 +316,8 @@ class ServeEngine:
         self.budget[slot] = req.max_new_tokens - 1
         self.slot_req[slot] = req
         self.outputs[req.rid] = Completion(
-            rid=req.rid, tokens=[int(tok)], prompt_len=len(req.prompt))
+            rid=req.rid, tokens=[_sync("first_token", lambda: int(tok))],
+            prompt_len=len(req.prompt))
 
     def _retire(self, slot: int):
         self.active[slot] = False
@@ -305,8 +325,9 @@ class ServeEngine:
 
     # -- main loop ----------------------------------------------------------
 
-    def run(self, requests: List[Request],
-            protocol=_UNSET, fault=_UNSET) -> Dict[int, Completion]:
+    def run(self, requests: List[Request], protocol=_UNSET, fault=_UNSET,
+            stop: Optional[Callable[[], bool]] = None
+            ) -> Dict[int, Completion]:
         """Serve ``requests`` to completion; returns ``{rid: Completion}``.
 
         Requests are admitted FIFO by ``arrival_tick`` (ties keep
@@ -321,6 +342,10 @@ class ServeEngine:
         ticks *degrading* completions per the model's policy instead of
         wedging the FIFO — every fault parameter is a traced leaf, so a
         fault sweep reuses the compiled tick too.
+
+        ``stop`` (optional, no arguments) is asked before each admission
+        round and before each tick; once it returns true the run ends and
+        returns the completions as they stand, unfinished ones included.
         """
         proto = self.config.protocol if protocol is _UNSET else protocol
         fm = self.config.fault if fault is _UNSET else fault
@@ -345,45 +370,61 @@ class ServeEngine:
             if not self.active.any() and not admissible:
                 tick = pending[0].arrival_tick   # idle: jump to next arrival
                 continue
+            if stop is not None and stop():
+                break
             for slot in range(self.B):
                 if not self.active[slot] and admissible:
-                    self._insert(slot, admissible.pop(0))
-            _DISPATCH_COUNTS["tick"] += 1
-            nxt, self.positions, self.cache, chan, fstate, flags = \
-                self._tick(self.values, proto, fm, fstate, self.cur_token,
-                           self.positions, self.cache, jnp.int32(tick))
-            self.cur_token = nxt[:, None]
-            tick += 1
-            if chan is not None:
-                total_slots += int(chan["contention_slots"])
-            if flags is not None and bool(flags["retrying"]):
-                # retry tick: the batch held position re-contending; bill
-                # the stall against every in-flight request and move on
-                for slot in range(self.B):
-                    if self.active[slot]:
-                        self.outputs[self.slot_req[slot].rid].retry_ticks \
-                            += 1
-                continue
-            degraded = flags is not None and not bool(flags["ok"])
-            nxt_np = np.asarray(nxt)
-            for slot in range(self.B):
-                if not self.active[slot]:
+                    req = admissible.pop(0)
+                    with obs.span("serve.admit", rid=req.rid, slot=slot):
+                        self._insert(slot, req)
+                    self.outputs[req.rid].token_ticks.append(tick)
+            if stop is not None and stop():
+                break
+            with obs.span("serve.tick", tick=tick):
+                obs.count(_DISPATCH + "tick")
+                nxt, self.positions, self.cache, chan, fstate, flags = \
+                    self._tick(self.values, proto, fm, fstate,
+                               self.cur_token, self.positions, self.cache,
+                               jnp.int32(tick))
+                self.cur_token = nxt[:, None]
+                tick += 1
+                if chan is not None:
+                    total_slots += _sync(
+                        "airtime", lambda: int(chan["contention_slots"]))
+                if flags is not None and _sync(
+                        "flags", lambda: bool(flags["retrying"])):
+                    # retry tick: the batch held position re-contending;
+                    # bill the stall against every in-flight request and
+                    # move on
+                    for slot in range(self.B):
+                        if self.active[slot]:
+                            self.outputs[self.slot_req[slot].rid] \
+                                .retry_ticks += 1
                     continue
-                req = self.slot_req[slot]
-                out = self.outputs[req.rid]
-                out.tokens.append(int(nxt_np[slot]))
-                out.uplink_bits += bits_per_tok
-                if degraded:
-                    out.degraded_tokens += 1
-                self.budget[slot] -= 1
-                done = (int(nxt_np[slot]) == self.eos
-                        or self.budget[slot] <= 0
-                        or int(self.positions[slot]) >= self.max_seq - 1)
-                if done:
-                    out.latency_ticks = tick - arrival_of[req.rid]
-                    out.channel_slots = (
-                        total_slots - slots_at_arrival[req.rid])
-                    self._retire(slot)
+                degraded = flags is not None and not _sync(
+                    "flags", lambda: bool(flags["ok"]))
+                nxt_np = _sync("tokens", lambda: np.asarray(nxt))
+                for slot in range(self.B):
+                    if not self.active[slot]:
+                        continue
+                    req = self.slot_req[slot]
+                    out = self.outputs[req.rid]
+                    out.tokens.append(int(nxt_np[slot]))
+                    out.token_ticks.append(tick - 1)
+                    out.uplink_bits += bits_per_tok
+                    if degraded:
+                        out.degraded_tokens += 1
+                    self.budget[slot] -= 1
+                    done = (int(nxt_np[slot]) == self.eos
+                            or self.budget[slot] <= 0
+                            or _sync("positions",
+                                     lambda: int(self.positions[slot]))
+                            >= self.max_seq - 1)
+                    if done:
+                        out.latency_ticks = tick - arrival_of[req.rid]
+                        out.channel_slots = (
+                            total_slots - slots_at_arrival[req.rid])
+                        self._retire(slot)
         return self.outputs
 
 

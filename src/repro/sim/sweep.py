@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import ocs
 from repro.sim import shard as sim_shard
 from repro.sim.scenarios import Scenario
@@ -42,13 +43,12 @@ from repro.sim.scenarios import Scenario
 # compilation observability
 # ---------------------------------------------------------------------------
 
-_TRACE_COUNTS: Dict[str, int] = {"clean": 0, "noisy": 0}
+_TRACE = "sweep.trace."
 
 
 def reset_trace_counts() -> None:
     """Zero the per-engine jit trace counters (used by tests/benchmarks)."""
-    for k in _TRACE_COUNTS:
-        _TRACE_COUNTS[k] = 0
+    obs.reset(_TRACE)
 
 
 def trace_counts() -> Dict[str, int]:
@@ -58,7 +58,7 @@ def trace_counts() -> Dict[str, int]:
     functions, which only executes while JAX traces — cache hits leave them
     untouched.
     """
-    return dict(_TRACE_COUNTS)
+    return obs.view(_TRACE, ("clean", "noisy"))
 
 
 # ---------------------------------------------------------------------------
@@ -81,7 +81,7 @@ def _shard_scenarios(fn, n_devices: int, n_args: int):
 def _sweep_clean(h, mask, id_bits, n_channels, *, bits, max_id_bits,
                  n_devices=1):
     """h: (S, R, N_max, K); mask: (S, N_max); id_bits/n_channels: (S,)."""
-    _TRACE_COUNTS["clean"] += 1
+    obs.count(_TRACE + "clean")
     core = functools.partial(ocs.ocs_maxpool_core,
                              bits=bits, max_id_bits=max_id_bits)
     per_round = jax.vmap(core, in_axes=(0, None, None))
@@ -103,7 +103,7 @@ def _sweep_noisy(h, mask, id_bits, rng, p_miss, n_channels, *,
     scalar broadcast — bit-for-bit the historical scalar path).
     ``backend`` selects the contention engine (``Protocol.backend``:
     ``"scan"`` or the fused ``"pallas"`` kernel, bit-for-bit identical)."""
-    _TRACE_COUNTS["noisy"] += 1
+    obs.count(_TRACE + "noisy")
     core = functools.partial(ocs.ocs_maxpool_noisy_core, bits=bits,
                              max_id_bits=max_id_bits, max_rounds=max_rounds,
                              backend=backend)

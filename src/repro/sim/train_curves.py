@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import vertical
 from repro.core.vertical import VerticalConfig
 from repro.data.vertical_data import PatchTaskConfig, patch_classification
@@ -77,14 +78,13 @@ from repro.train.train_step import make_train_step
 # ---------------------------------------------------------------------------
 
 _COUNTER_KEYS = ("fused", "sched", "fused_dp", "fused_faults")
-_TRACE_COUNTS: Dict[str, int] = {k: 0 for k in _COUNTER_KEYS}
-_DISPATCH_COUNTS: Dict[str, int] = {k: 0 for k in _COUNTER_KEYS}
+_TRACE = "curves.trace."
+_DISPATCH = "curves.dispatch."
 
 
 def reset_trace_counts() -> None:
     """Zero the per-engine jit trace counters (used by tests/benchmarks)."""
-    for k in _TRACE_COUNTS:
-        _TRACE_COUNTS[k] = 0
+    obs.reset(_TRACE)
 
 
 def trace_counts() -> Dict[str, int]:
@@ -92,13 +92,12 @@ def trace_counts() -> Dict[str, int]:
     costs exactly one ``fused`` trace per ``bits`` value (one ``sched``
     trace per :func:`run_scheduled_curves`), no matter how many ``p_miss``
     lanes the grid has."""
-    return dict(_TRACE_COUNTS)
+    return obs.view(_TRACE, _COUNTER_KEYS)
 
 
 def reset_dispatch_counts() -> None:
     """Zero the per-engine host-dispatch counters."""
-    for k in _DISPATCH_COUNTS:
-        _DISPATCH_COUNTS[k] = 0
+    obs.reset(_DISPATCH)
 
 
 def dispatch_counts() -> Dict[str, int]:
@@ -111,7 +110,7 @@ def dispatch_counts() -> Dict[str, int]:
     ``<= ceil(steps/log_every) + 2`` per-bits bound, guarding the fused
     call structure against falling back to per-step driving.
     """
-    return dict(_DISPATCH_COUNTS)
+    return obs.view(_DISPATCH, _COUNTER_KEYS)
 
 
 # ---------------------------------------------------------------------------
@@ -488,7 +487,7 @@ def _make_fused(ccfg: CurveConfig, per_bits, n_logged: int, n_dev: int):
 
     def fused(params0, opt0, lane_keys, p, k_data, views, labels, vviews,
               vlabels, slots):
-        _TRACE_COUNTS["fused"] += 1
+        obs.count(_TRACE + "fused")
         n_out = noisy_engine(params0, opt0, lane_keys, p, k_data, views,
                              labels, vviews, vlabels, slots)
         i_out = ideal_lanes(params0, opt0, k_data, views, labels, vviews,
@@ -529,7 +528,7 @@ def _run_curves_scan(ccfg: CurveConfig, n_devices) -> CurveResult:
         opt0 = opt.init(params0)
 
         fused = _make_fused(ccfg, per_bits, len(logged), n_dev)
-        _DISPATCH_COUNTS["fused"] += 1
+        obs.count(_DISPATCH + "fused")
         n_out, i_out = fused(params0, opt0, keys_pad, p_pad, k_data,
                              views_j, labels_j, vv_j, vl_j, slots)
         vals_n, hist_n, acc_n, nll_n = n_out
@@ -697,7 +696,7 @@ def _make_fused_faults(ccfg: CurveConfig, per_bits, n_logged: int):
 
     def fused(params0, opt0, lane_keys, fm, fs0, k_data, views, labels,
               vviews, vlabels, slots):
-        _TRACE_COUNTS["fused_faults"] += 1
+        obs.count(_TRACE + "fused_faults")
         return fault_lanes_fn(params0, opt0, lane_keys, fm, fs0, k_data,
                               views, labels, vviews, vlabels, slots)
 
@@ -761,7 +760,7 @@ def run_fault_curves(ccfg: CurveConfig, fault_lanes: Sequence
                               (ccfg.batch, ccfg.embed_dim)), lanes)
 
         fused = _make_fused_faults(ccfg, per_bits, len(logged))
-        _DISPATCH_COUNTS["fused_faults"] += 1
+        obs.count(_DISPATCH + "fused_faults")
         (vals, hist_b, stale_b, drop_b, out_b, retry_b, acc_b,
          nll_b) = fused(params0, opt0, jnp.asarray(lane_keys), fm_stacked,
                         fs0, k_data, views_j, labels_j, vv_j, vl_j, slots)
@@ -828,7 +827,7 @@ def _make_sched_fused(ccfg: CurveConfig, schedule: BitsSchedule, per_cand,
 
     def fused(params0, opt0, lane_keys, p, k_data, views, labels, vviews,
               vlabels, slots):
-        _TRACE_COUNTS["sched"] += 1
+        obs.count(_TRACE + "sched")
         eval_branches = [make_eval_branch(ci, vviews, vlabels)
                          for ci in range(len(schedule.candidates))]
         lanes = lane_keys.shape[0]
@@ -908,7 +907,7 @@ def run_scheduled_curves(ccfg: CurveConfig, schedule: BitsSchedule
     opt0 = opt.init(params0)
 
     fused = _make_sched_fused(ccfg, schedule, per_cand, len(logged))
-    _DISPATCH_COUNTS["sched"] += 1
+    obs.count(_DISPATCH + "sched")
     vals, hist, coll_hist, bits_seq, acc, nll = fused(
         params0, opt0, jnp.asarray(lane_keys), p_lanes, k_data, views_j,
         labels_j, vv_j, vl_j, slots)
@@ -1050,7 +1049,7 @@ def _make_fused_dp(ccfg: CurveConfig, compress: CompressedAllReduce,
 
     def fused(params0, opt0, err0, lane_keys, p, shard_ids, k_data, views,
               labels, vviews, vlabels, slots):
-        _TRACE_COUNTS["fused_dp"] += 1
+        obs.count(_TRACE + "fused_dp")
         return dp_engine(params0, opt0, err0, lane_keys, p, shard_ids,
                          k_data, views, labels, vviews, vlabels, slots)
 
@@ -1097,7 +1096,7 @@ def _run_curves_dp(ccfg: CurveConfig, compress: CompressedAllReduce,
 
         fused = _make_fused_dp(ccfg, compress, per_bits, len(logged), n_s,
                                n_d)
-        _DISPATCH_COUNTS["fused_dp"] += 1
+        obs.count(_DISPATCH + "fused_dp")
         vals, hist_b, pay_b, pay_tot_b, acc_b, nll_b = fused(
             params0, opt0, err0, keys_pad, p_pad, shard_ids, k_data,
             views_j, labels_j, vv_j, vl_j, slots)

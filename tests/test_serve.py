@@ -17,12 +17,15 @@ The redesign contracts pinned here:
 """
 
 import dataclasses
+import pathlib
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import get_reduced
 from repro.models import model as M
 from repro.parallel.sharding import split_tree
@@ -31,6 +34,10 @@ from repro.serve import engine as se
 from repro.serve.engine import (ChannelClock, Completion, Request,
                                 ServeConfig, ServeEngine)
 from repro.serve.load import near_far_protocol, poisson_requests
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
 
 N_WORKERS = 2
 VOCAB = 64
@@ -235,6 +242,86 @@ def test_one_dispatch_per_decode_tick(model_and_values):
     ticks = se.dispatch_counts()["tick"]
     decode_tokens = sum(len(c.tokens) - 1 for c in outs.values())
     assert -(-decode_tokens // 2) <= ticks <= decode_tokens
+
+
+# -- spans, per-token delivery ticks, stop hook -----------------------------
+
+def _budget_requests():
+    return [Request(rid=i, prompt=np.arange(4, dtype=np.int32) + i,
+                    max_new_tokens=2 + i % 4) for i in range(7)]
+
+
+@pytest.mark.parametrize("p_miss", [None, 0.1])
+def test_host_syncs_per_tick_follow_the_loop(model_and_values, p_miss):
+    """Every tick reads its tokens (and, with the channel, its airtime)
+    once, and the position of each active slot its budget did not retire:
+    the ``or`` in the stopping rule skips that read."""
+    m, values = model_and_values
+    proto = None if p_miss is None else _ocs(p_miss)
+    eng = _engine(m, values, batch_slots=3, max_seq=40, eos_id=-1,
+                  protocol=proto)
+    with obs.recording() as spans:
+        outs = eng.run(_budget_requests())
+    ticks = [(a["tick"], t0, t1) for n, t0, t1, a in spans
+             if n == "serve.tick"]
+    assert ticks
+    for tick, t0, t1 in ticks:
+        syncs = [a["what"] for n, s0, s1, a in spans
+                 if n == "serve.sync" and t0 <= s0 and s1 <= t1]
+        decoding = [c for c in outs.values() if tick in c.token_ticks[1:]]
+        kept = sum(c.token_ticks[-1] != tick for c in decoding)
+        assert len(syncs) == kept + 1 + (proto is not None)
+        assert syncs.count("tokens") == 1
+        assert syncs.count("airtime") == (proto is not None)
+    admits = [n for n, _, _, _ in spans if n == "serve.admit"]
+    firsts = [a for n, _, _, a in spans
+              if n == "serve.sync" and a["what"] == "first_token"]
+    assert len(admits) == len(firsts) == len(outs)
+
+
+def test_token_ticks_follow_the_harness_recorder(model_and_values):
+    """One tick per token, never decreasing, and the decode ticks are
+    those the benchmark's tick wrapper saw for the request."""
+    from bench.drivers.serve import Recorder
+    from bench.lib.harness import Spans
+
+    m, values = model_and_values
+    eng = _engine(m, values, batch_slots=3, max_seq=40, eos_id=-1,
+                  protocol=_ocs(0.1))
+    rec = Recorder(eng, Spans())
+    try:
+        outs = eng.run(_budget_requests())
+    finally:
+        rec.restore()
+    ticks = rec.decode_ticks()
+    for rid, c in outs.items():
+        assert len(c.token_ticks) == len(c.tokens)
+        assert c.token_ticks == sorted(c.token_ticks)
+        assert c.token_ticks[1:] == ticks[rid][1]
+        assert c.token_ticks[1] >= c.token_ticks[0]
+
+
+def test_stop_ends_a_run_with_partial_completions(model_and_values):
+    m, values = model_and_values
+    eng = _engine(m, values, batch_slots=2, max_seq=40, eos_id=-1)
+    full = {rid: list(c.tokens)
+            for rid, c in eng.run(_budget_requests()).items()}
+    asked = []
+
+    def stop():
+        asked.append(1)
+        return len(asked) > 5
+
+    se.reset_dispatch_counts()
+    part = eng.run(_budget_requests(), stop=stop)
+    assert len(asked) == 6
+    # two asks per round (admission, tick): the third round's tick never ran
+    assert se.dispatch_counts()["tick"] == 2
+    assert 0 < len(part) < len(full)
+    assert any(len(c.tokens) < len(full[rid]) for rid, c in part.items())
+    for rid, c in part.items():
+        assert c.tokens == full[rid][:len(c.tokens)]
+        assert len(c.token_ticks) == len(c.tokens)
 
 
 # -- load generation --------------------------------------------------------
