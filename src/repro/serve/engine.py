@@ -29,7 +29,8 @@ The host loop is spanned (:func:`repro.obs.span`): ``serve.admit`` around
 each admission (``rid``, ``slot``), ``serve.tick`` from a tick's dispatch
 to the end of its per-slot bookkeeping (``tick``), and ``serve.sync``
 around every device->host read the loop makes (``what``: ``tokens``,
-``positions``, ``airtime``, ``flags`` or ``first_token``).
+``airtime``, ``flags`` or ``first_token``).  The loop never reads a slot's
+position back: the host derives it from the tokens it has delivered.
 """
 
 from __future__ import annotations
@@ -415,10 +416,12 @@ class ServeEngine:
                     if degraded:
                         out.degraded_tokens += 1
                     self.budget[slot] -= 1
+                    # the slot's device position, derived on the host: the
+                    # prefill left it at prompt_len with one token out, and
+                    # each committed tick adds one of each
                     done = (int(nxt_np[slot]) == self.eos
                             or self.budget[slot] <= 0
-                            or _sync("positions",
-                                     lambda: int(self.positions[slot]))
+                            or out.prompt_len + len(out.tokens) - 1
                             >= self.max_seq - 1)
                     if done:
                         out.latency_ticks = tick - arrival_of[req.rid]

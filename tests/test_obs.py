@@ -115,4 +115,4 @@ def test_profiler_trace_holds_the_serve_spans_on_a_host_plane(
     assert [s["tick"] for s in found["repro.serve.tick"]] == list(
         range(len(found["repro.serve.tick"])))
     assert {s["what"] for s in found["repro.serve.sync"]} == {
-        "tokens", "positions", "first_token"}
+        "tokens", "first_token"}

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import obs
+from repro import faults, obs
 from repro.configs import get_reduced
 from repro.models import model as M
 from repro.parallel.sharding import split_tree
@@ -254,8 +254,8 @@ def _budget_requests():
 @pytest.mark.parametrize("p_miss", [None, 0.1])
 def test_host_syncs_per_tick_follow_the_loop(model_and_values, p_miss):
     """Every tick reads its tokens (and, with the channel, its airtime)
-    once, and the position of each active slot its budget did not retire:
-    the ``or`` in the stopping rule skips that read."""
+    once and nothing else: the stopping rule derives each slot's position
+    on the host and never reads it back from the device."""
     m, values = model_and_values
     proto = None if p_miss is None else _ocs(p_miss)
     eng = _engine(m, values, batch_slots=3, max_seq=40, eos_id=-1,
@@ -268,15 +268,66 @@ def test_host_syncs_per_tick_follow_the_loop(model_and_values, p_miss):
     for tick, t0, t1 in ticks:
         syncs = [a["what"] for n, s0, s1, a in spans
                  if n == "serve.sync" and t0 <= s0 and s1 <= t1]
-        decoding = [c for c in outs.values() if tick in c.token_ticks[1:]]
-        kept = sum(c.token_ticks[-1] != tick for c in decoding)
-        assert len(syncs) == kept + 1 + (proto is not None)
+        assert len(syncs) == 1 + (proto is not None)
         assert syncs.count("tokens") == 1
         assert syncs.count("airtime") == (proto is not None)
+        assert "positions" not in syncs
     admits = [n for n, _, _, _ in spans if n == "serve.admit"]
     firsts = [a for n, _, _, a in spans
               if n == "serve.sync" and a["what"] == "first_token"]
     assert len(admits) == len(firsts) == len(outs)
+
+
+def _retry_outage():
+    # every worker drops and none recovers: the first ticks retry (holding
+    # every position), then the exhausted budget commits degraded tokens
+    return faults.FaultModel.iid(
+        0.0, policy=faults.DegradePolicy.retry(2)).with_dropout(1.0, 0.0)
+
+
+def _assert_positions_derived(eng):
+    """A ``stop`` hook: before every tick, each active slot's device
+    position is the one the host derives from its delivered tokens."""
+    def stop():
+        positions = np.asarray(eng.positions)
+        for slot in np.flatnonzero(eng.active):
+            out = eng.outputs[eng.slot_req[slot].rid]
+            assert (out.prompt_len + len(out.tokens) - 1
+                    == int(positions[slot]))
+        return False
+    return stop
+
+
+@pytest.mark.parametrize("channel", ["off", "ocs", "retry_outage"])
+def test_derived_position_matches_the_device_every_tick(model_and_values,
+                                                         channel):
+    m, values = model_and_values
+    kw = {} if channel == "off" else {"protocol": _ocs(0.1)}
+    if channel == "retry_outage":
+        kw["fault"] = _retry_outage()
+    eng = _engine(m, values, batch_slots=3, max_seq=40, eos_id=-1, **kw)
+    outs = eng.run(_budget_requests(), stop=_assert_positions_derived(eng))
+    for r in _budget_requests():
+        assert len(outs[r.rid].tokens) == r.max_new_tokens
+    if channel == "retry_outage":
+        assert all(c.retry_ticks > 0 for c in outs.values()
+                   if c.token_ticks[0] == 0)
+
+
+def test_length_cap_retires_each_slot_at_max_seq(model_and_values):
+    """Prompts of different lengths share the batch: each slot retires at
+    ``max_seq - 1`` by its own derived position, with the reference's
+    tokens."""
+    m, values = model_and_values
+    max_seq = 12
+    reqs = [Request(rid=i, prompt=np.arange(3 + 2 * i, dtype=np.int32) + i,
+                    max_new_tokens=100) for i in range(4)]
+    eng = _engine(m, values, batch_slots=3, max_seq=max_seq, eos_id=-1)
+    outs = eng.run(reqs, stop=_assert_positions_derived(eng))
+    ref = se.reference_tokens(m, values, reqs, max_seq)
+    for r in reqs:
+        assert len(outs[r.rid].tokens) == max_seq - len(r.prompt)
+        assert outs[r.rid].tokens == ref[r.rid]
 
 
 def test_token_ticks_follow_the_harness_recorder(model_and_values):
