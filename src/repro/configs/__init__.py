@@ -19,6 +19,7 @@ _MODULES = {
     "pixtral-12b": "pixtral_12b",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "whisper-base": "whisper_base",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCH_IDS = tuple(_MODULES)

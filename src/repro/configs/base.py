@@ -14,7 +14,7 @@ from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 
-MIXERS = ("attn", "attn_nocausal", "mamba", "mlstm", "slstm")
+MIXERS = ("attn", "attn_nocausal", "mla", "mamba", "mlstm", "slstm")
 FFNS = ("mlp", "moe", "none")
 TP_FUSIONS = ("sum", "max", "max_q16", "max_q8", "concat")
 
@@ -33,11 +33,19 @@ class ModelConfig:
     # layer plan: patterns are cycled over the layer index
     block_pattern: Tuple[str, ...] = ("attn",)
     ffn_pattern: Tuple[str, ...] = ("mlp",)
+    # leading layers ahead of the pattern (deepseek's first_k_dense_replace):
+    # the pattern's first mixer with an mlp FFN of width d_ff
+    first_dense_layers: int = 0
     # MoE
     n_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0
     moe_shared_expert: bool = False
+    moe_shared_d_ff: int = 0          # shared expert width; 0 => moe_d_ff
+    moe_score: str = "softmax"        # router scores: softmax | sigmoid
+    moe_select_bias: bool = False     # per-expert bias on the scores, for
+    #                                   selection only (noaux_tc)
+    moe_routed_scale: float = 1.0     # routed_scaling_factor
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     # attention
@@ -46,6 +54,12 @@ class ModelConfig:
     rotary_frac: float = 1.0          # glm4 rotates half the head dim
     use_rope: bool = True             # rotary embeddings inside attention
     use_abs_pos: bool = False         # additive sinusoidal PE (whisper)
+    # multi-head latent attention (mixer "mla"; the query is projected
+    # directly, as with deepseek's q_lora_rank null)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # SSM (mamba / xlstm)
     ssm_state_dim: int = 16
     ssm_expand: int = 2
@@ -90,9 +104,13 @@ class ModelConfig:
             assert m in MIXERS, m
         for f in self.ffn_pattern:
             assert f in FFNS, f
+        assert self.moe_score in ("softmax", "sigmoid"), self.moe_score
         period = self.period
-        assert self.n_layers % period == 0, \
-            f"{self.name}: n_layers {self.n_layers} % period {period} != 0"
+        stacked = self.n_layers - self.first_dense_layers
+        assert stacked > 0 and stacked % period == 0, \
+            f"{self.name}: n_layers {self.n_layers} - first_dense_layers " \
+            f"{self.first_dense_layers} is not a whole number of periods " \
+            f"of {period}"
 
     # ---- derived ----
     @property
@@ -101,7 +119,7 @@ class ModelConfig:
 
     @property
     def n_periods(self) -> int:
-        return self.n_layers // self.period
+        return (self.n_layers - self.first_dense_layers) // self.period
 
     def layer_plan(self) -> Tuple[Tuple[str, str], ...]:
         """(mixer, ffn) for each position within a period."""
@@ -109,6 +127,10 @@ class ModelConfig:
             (self.block_pattern[i % len(self.block_pattern)],
              self.ffn_pattern[i % len(self.ffn_pattern)])
             for i in range(self.period))
+
+    def lead_plan(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, ffn) of each leading dense layer."""
+        return ((self.block_pattern[0], "mlp"),)
 
     def encoder_layer_plan(self) -> Tuple[Tuple[str, str], ...]:
         return tuple(
@@ -148,6 +170,14 @@ def _attn_params(c: ModelConfig) -> int:
     return p
 
 
+def _mla_params(c: ModelConfig) -> int:
+    h, r = c.n_heads, c.kv_lora_rank
+    return (c.d_model * h * (c.qk_nope_head_dim + c.qk_rope_head_dim)
+            + c.d_model * (r + c.qk_rope_head_dim) + r
+            + r * h * (c.qk_nope_head_dim + c.v_head_dim)
+            + h * c.v_head_dim * c.d_model)
+
+
 def _mlp_params(c: ModelConfig, d_ff: int) -> int:
     gates = 2 if c.act == "silu" else 1          # SwiGLU has gate+up
     return c.d_model * d_ff * gates + d_ff * c.d_model
@@ -177,6 +207,8 @@ def _layer_params(c: ModelConfig, mixer: str, ffn: str) -> Tuple[int, int]:
     """(dense_params, per_expert_extra) for one layer."""
     if mixer in ("attn", "attn_nocausal"):
         p = _attn_params(c)
+    elif mixer == "mla":
+        p = _mla_params(c)
     elif mixer == "mamba":
         p = _mamba_params(c)
     else:
@@ -189,7 +221,7 @@ def _layer_params(c: ModelConfig, mixer: str, ffn: str) -> Tuple[int, int]:
         p += c.d_model * c.n_experts     # router
         moe_extra = _mlp_params(c, c.moe_d_ff or c.d_ff)
         if c.moe_shared_expert:
-            p += _mlp_params(c, c.moe_d_ff or c.d_ff)
+            p += _mlp_params(c, c.moe_shared_d_ff or c.moe_d_ff or c.d_ff)
     return p, moe_extra
 
 
@@ -200,7 +232,9 @@ def _param_count(c: ModelConfig, active_only: bool) -> int:
     if c.frontend != "token":
         total += (c.frontend_dim or c.d_model) * c.d_model
     plan = c.layer_plan()
-    for i in range(c.n_layers):
+    for mixer, ffn in c.lead_plan() * c.first_dense_layers:
+        total += _layer_params(c, mixer, ffn)[0]
+    for i in range(c.n_layers - c.first_dense_layers):
         mixer, ffn = plan[i % c.period]
         dense, per_expert = _layer_params(c, mixer, ffn)
         total += dense

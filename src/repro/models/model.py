@@ -12,12 +12,19 @@ train (patch/audio)      {"feats": (B,S,Df) bf16, "targets": (B,S) i32}
                          align with decoder tokens.
 prefill                  same as train minus targets -> (last_logits, cache)
 decode                   (token (B,1) i32, positions (B,) i32, cache)
+
+Layers: a config's leading dense layers (``first_dense_layers``) are a
+scanned stack of their own, ``"lead"``, ahead of the main stack
+``"blocks"`` in the parameter tree and, for such a config, in the decode
+cache (``{"lead": ..., "blocks": ...}``; otherwise the cache is the main
+stack's alone).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import operator
 import types
 from typing import Any, Dict, Optional, Tuple
 
@@ -25,10 +32,27 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.models import layers, transformer
+from repro.models import fusion, layers, transformer
 from repro.parallel.sharding import Tagged, constrain, split_tree
 
 WHISPER_DECODER_LEN = 448   # whisper's real positional cap for train targets
+_LEAD_KEY = 0x1EAD          # folded into the lead stack's keys
+
+
+def _stacks(cfg):
+    """The decoder's scanned stacks in order: ``(key, plan, n_periods)``."""
+    lead = ((("lead", cfg.lead_plan(), cfg.first_dense_layers),)
+            if cfg.first_dense_layers else ())
+    return lead + (("blocks", cfg.layer_plan(), cfg.n_periods),)
+
+
+def _pack(cfg, caches: dict) -> dict:
+    """The decode cache from each stack's cache, by stack key."""
+    return caches if cfg.first_dense_layers else caches["blocks"]
+
+
+def _unpack(cfg, cache: dict) -> dict:
+    return dict(cache) if cfg.first_dense_layers else {"blocks": cache}
 
 
 # ---------------------------------------------------------------------------
@@ -44,6 +68,10 @@ def init(cfg: ModelConfig, rng: jax.Array) -> dict:
                                          cross=cfg.encoder_decoder),
         "final_norm": layers.norm_init(cfg, r[2]),
     }
+    if cfg.first_dense_layers:
+        p["lead"] = transformer.stack_init(
+            cfg, jax.random.fold_in(r[1], _LEAD_KEY), cfg.lead_plan(),
+            cfg.first_dense_layers)
     p.update(layers.unembed_init(cfg, r[3]))
     if cfg.encoder_decoder:
         enc_plan = cfg.encoder_layer_plan()
@@ -125,10 +153,13 @@ def forward(cfg, v, batch) -> Tuple[jax.Array, jax.Array]:
         enc_out = _encode(cfg, v, batch["feats"])
     x, positions = _embed_inputs(cfg, v, batch)
     x = constrain(x, ("batch", "seq", "embed"))
-    x, aux = transformer.stack_full(cfg, v["blocks"], x, positions,
-                                    cfg.layer_plan(), enc_out=enc_out)
+    auxes = []
+    for key, plan, _ in _stacks(cfg):
+        x, a = transformer.stack_full(cfg, v[key], x, positions, plan,
+                                      enc_out=enc_out)
+        auxes.append(a)
     x = layers.norm_apply(cfg, v["final_norm"], x)
-    return x, aux
+    return x, functools.reduce(operator.add, auxes)
 
 
 def logits_fn(cfg, v, batch) -> jax.Array:
@@ -156,14 +187,15 @@ def prefill(cfg, v, batch, max_seq: Optional[int] = None
         enc_out = _encode(cfg, v, batch["feats"])
     x, positions = _embed_inputs(cfg, v, batch)
     max_seq = max_seq or x.shape[1]
-    x, cache, _ = transformer.stack_prefill(
-        cfg, v["blocks"], x, positions, cfg.layer_plan(), max_seq,
-        enc_out=enc_out)
+    caches = {}
+    for key, plan, _ in _stacks(cfg):
+        x, caches[key], _ = transformer.stack_prefill(
+            cfg, v[key], x, positions, plan, max_seq, enc_out=enc_out)
     x = layers.norm_apply(cfg, v["final_norm"], x)
     last = x[:, -1:]
     logits = layers.unembed_apply(cfg, {k: v[k] for k in ("head",) if k in v},
                                   v["embed"], last)[:, 0]
-    return logits, cache
+    return logits, _pack(cfg, caches)
 
 
 def decode_step(cfg, v, token: jax.Array, positions: jax.Array, cache: dict
@@ -175,8 +207,11 @@ def decode_step(cfg, v, token: jax.Array, positions: jax.Array, cache: dict
         pe = layers.sinusoidal_positions(
             int(_max_pos(cfg, cache)), cfg.d_model).astype(x.dtype)
         x = x + pe[positions][:, None]
-    x, new_cache, _ = transformer.stack_step(cfg, v["blocks"], x, positions,
-                                             cache, cfg.layer_plan())
+    caches = _unpack(cfg, cache)
+    for key, plan, _ in _stacks(cfg):
+        x, caches[key], _ = transformer.stack_step(cfg, v[key], x, positions,
+                                                   caches[key], plan)
+    new_cache = _pack(cfg, caches)
     x = layers.norm_apply(cfg, v["final_norm"], x)
     logits = layers.unembed_apply(cfg, {k: v[k] for k in ("head",) if k in v},
                                   v["embed"], x)[:, 0]
@@ -188,8 +223,9 @@ def decode_step_channel(cfg, v, token: jax.Array, positions: jax.Array,
                         ) -> Tuple[jax.Array, dict, dict]:
     """:func:`decode_step` with the wireless channel in the loop.
 
-    Every mlp-FFN worker fusion in the stack aggregates the per-worker
-    partials through ``protocol`` (a traced ``repro.protocol.Protocol``
+    Every worker-factored FFN in the stack (each mlp FFN and each MoE
+    layer's shared expert) aggregates the per-worker partials through
+    ``protocol`` (a traced ``repro.protocol.Protocol``
     pytree — rebinding ``p_miss`` never recompiles) under the sensing key
     ``rng``; mixer fusions stay on the ideal ``tp_fusion`` collective.
     Returns ``(logits, new_cache, chan)`` where ``chan`` is the summed
@@ -201,9 +237,16 @@ def decode_step_channel(cfg, v, token: jax.Array, positions: jax.Array,
         pe = layers.sinusoidal_positions(
             int(_max_pos(cfg, cache)), cfg.d_model).astype(x.dtype)
         x = x + pe[positions][:, None]
-    x, new_cache, _, chan = transformer.stack_step(
-        cfg, v["blocks"], x, positions, cache, cfg.layer_plan(),
-        protocol=protocol, rng=rng)
+    caches = _unpack(cfg, cache)
+    chans = []
+    for key, plan, _ in _stacks(cfg):
+        k = rng if key == "blocks" else jax.random.fold_in(rng, _LEAD_KEY)
+        x, caches[key], _, ch = transformer.stack_step(
+            cfg, v[key], x, positions, caches[key], plan,
+            protocol=protocol, rng=k)
+        chans.append(ch)
+    chan = functools.reduce(fusion.chan_merge, chans)
+    new_cache = _pack(cfg, caches)
     x = layers.norm_apply(cfg, v["final_norm"], x)
     logits = layers.unembed_apply(cfg, {k: v[k] for k in ("head",) if k in v},
                                   v["embed"], x)[:, 0]
@@ -211,9 +254,14 @@ def decode_step_channel(cfg, v, token: jax.Array, positions: jax.Array,
 
 
 def channel_sites(cfg) -> int:
-    """Channel aggregate calls per decode tick: one per mlp-FFN layer."""
-    return cfg.n_periods * sum(1 for _, ffn in cfg.layer_plan()
-                               if ffn == "mlp")
+    """Channel aggregate calls per decode tick: one per worker-factored
+    FFN, that is each mlp layer and each MoE layer's shared expert (routed
+    experts stay whole on their shard)."""
+    def sites(plan):
+        return sum(1 for _, ffn in plan
+                   if ffn == "mlp" or (ffn == "moe" and cfg.moe_shared_expert))
+
+    return sum(n * sites(plan) for _, plan, n in _stacks(cfg))
 
 
 def _max_pos(cfg, cache) -> int:
@@ -226,14 +274,16 @@ def _max_pos(cfg, cache) -> int:
 
 
 def cache_init(cfg, batch: int, max_seq: int, cross_len: int = 0) -> dict:
-    return transformer.stack_cache_init(
-        cfg, cfg.layer_plan(), cfg.n_periods, batch, max_seq, cfg.dtype,
-        cross_len=cross_len)
+    return _pack(cfg, {key: transformer.stack_cache_init(
+        cfg, plan, n, batch, max_seq, cfg.dtype, cross_len=cross_len)
+        for key, plan, n in _stacks(cfg)})
 
 
 def cache_axes(cfg) -> dict:
-    return transformer.stack_cache_axes(cfg, cfg.layer_plan(),
-                                        cfg.encoder_decoder)
+    """Logical axes of every leaf of :func:`cache_init`'s tree (each a
+    tuple naming ``"batch"`` among them)."""
+    return _pack(cfg, {key: transformer.stack_cache_axes(
+        cfg, plan, cfg.encoder_decoder) for key, plan, _ in _stacks(cfg)})
 
 
 # ---------------------------------------------------------------------------

@@ -1,21 +1,41 @@
 """Mixture-of-Experts FFN with expert parallelism (EP).
 
 Experts are sharded over the ``model`` mesh axis (one shard owns
-``n_experts / N`` whole expert FFNs).  Routing uses *per-sequence grouped
-dispatch*: top-k selection, a sort **within each sequence** (vmapped — never a
-global cross-shard sort), and capacity-bounded scatter into per-expert
-buffers.  The scatter/gather between the batch-sharded token axis and the
-expert-sharded buffer axis is where GSPMD emits the EP all-to-all.
+``n_experts / N`` whole expert FFNs).
 
-Dropped-token policy: tokens beyond ``capacity_factor``-scaled capacity are
-dropped (scatter with out-of-bounds position — JAX drops OOB scatter updates),
-standard Switch/GShard semantics.  The router adds the usual load-balancing
-auxiliary loss.
+Router: scores are a softmax over the experts (default) or, with
+``moe_score="sigmoid"``, each expert's sigmoid; a router with
+``moe_select_bias`` adds a per-expert bias to the scores to *select* the
+top-k (DeepSeek-V3 ``noaux_tc``) and weights the chosen experts by the
+scores alone.  The weights are renormalised to sum 1 and scaled by
+``moe_routed_scale``.
 
-The FedOCS fusion law does not apply inside expert FFNs (DESIGN.md §5): an
-expert's FFN lives wholly on one shard, so there is no cross-worker partial
-reduction to replace.  A shared expert (llama4-style), which *is* worker-
-sharded, uses the standard MLP path and therefore does participate.
+Two dispatch paths over the same routing:
+
+* Dropless (``dropless=True``; prefill and decode, ``transformer.py``):
+  every token reaches each of its top-k experts.  The (token, expert)
+  rows are sorted by expert and the expert FFNs run as ragged matmuls
+  (``jax.lax.ragged_dot``) over the groups, so their FLOPs scale with the
+  routed rows.
+* Capacity-bounded (training and the full forward): *per-sequence grouped
+  dispatch*, top-k selection, a sort **within each sequence** (vmapped,
+  never a global cross-shard sort) and a scatter into per-expert buffers
+  of ``capacity_factor``-scaled capacity.  Tokens beyond it are dropped
+  (scatter with an out-of-bounds position; JAX drops OOB scatter
+  updates), standard Switch/GShard semantics, with the usual
+  load-balancing auxiliary loss.  The scatter/gather between the
+  batch-sharded token axis and the expert-sharded buffer axis is where
+  GSPMD emits the EP all-to-all.
+
+Each traced MoE layer counts its path in :mod:`repro.obs`
+(``moe.path.dropless`` / ``moe.path.capacity``), and its device work sits
+under the name scopes ``moe.route`` (router, top-k, sort and permutation,
+combine) and ``moe.experts`` (the routed expert matmuls).
+
+The FedOCS fusion law does not apply inside routed expert FFNs: an expert's
+FFN lives wholly on one shard, so there is no cross-worker partial
+reduction to replace.  The shared expert, which *is* worker-factored, uses
+the standard MLP path and with a protocol pools through the channel.
 """
 
 from __future__ import annotations
@@ -26,8 +46,11 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import layers, mlp
 from repro.parallel.sharding import constrain
+
+PATH_COUNTER = "moe.path."
 
 
 def moe_init(cfg, rng) -> dict:
@@ -44,9 +67,35 @@ def moe_init(cfg, rng) -> dict:
         "w_down": layers.param(r[3], (e, f, d), ("experts", "ff_local", "embed"),
                                cfg.param_dtype, scale=f ** -0.5),
     }
+    if cfg.moe_select_bias:
+        p["select_bias"] = layers.param(r[0], (e,), (None,), jnp.float32,
+                                        mode="zeros")
     if cfg.moe_shared_expert:
-        p["shared"] = mlp.mlp_init(cfg, r[4], d_ff=cfg.moe_d_ff or cfg.d_ff)
+        p["shared"] = mlp.mlp_init(
+            cfg, r[4], d_ff=cfg.moe_shared_d_ff or cfg.moe_d_ff or cfg.d_ff)
     return p
+
+
+def router_scores(cfg, p: dict, x: jax.Array) -> jax.Array:
+    """(..., d) -> (..., E) float32 scores of every expert (the router's
+    product in full float32, as DeepSeek's gate computes it)."""
+    logits = jnp.matmul(x.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    if cfg.moe_score == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def select(cfg, scores: jax.Array, bias=None):
+    """Each token's top-k experts and their weights, ``(idx, w)`` each
+    (..., k): chosen by ``scores + bias`` (the bias steers selection only),
+    weighted by ``scores`` renormalised over the k and scaled by
+    ``moe_routed_scale``."""
+    k = cfg.experts_per_token
+    _, idx = jax.lax.top_k(scores if bias is None else scores + bias, k)
+    w = jnp.take_along_axis(scores, idx, -1)
+    w = w / jnp.clip(jnp.sum(w, -1, keepdims=True), 1e-9)
+    return idx, w * cfg.moe_routed_scale
 
 
 def _capacity(cfg, tokens_per_seq: int) -> int:
@@ -55,16 +104,15 @@ def _capacity(cfg, tokens_per_seq: int) -> int:
         * cfg.capacity_factor))
 
 
-def _route_one_seq(cfg, probs: jax.Array, cap: int):
-    """probs: (S, E) -> dispatch indices for one sequence.
+def _route_one_seq(cfg, probs: jax.Array, cap: int, bias=None):
+    """probs: (S, E) router scores -> dispatch indices for one sequence.
 
     Returns (expert_idx, pos_in_expert, token_idx, weight), each (S*k,),
     with pos_in_expert == cap for dropped tokens (OOB scatter -> dropped).
     """
     s, e = probs.shape
     k = cfg.experts_per_token
-    w, idx = jax.lax.top_k(probs, k)                     # (S, k)
-    w = w / jnp.clip(jnp.sum(w, -1, keepdims=True), 1e-9)
+    idx, w = select(cfg, probs, bias)                    # (S, k)
     e_flat = idx.reshape(-1)                             # (S*k,)
     w_flat = w.reshape(-1)
     tok_flat = jnp.repeat(jnp.arange(s, dtype=jnp.int32), k)
@@ -77,10 +125,60 @@ def _route_one_seq(cfg, probs: jax.Array, cap: int):
     return e_s, pos, t_s, w_s
 
 
-def moe_apply(cfg, p: dict, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    if cfg.moe_impl == "gather":
-        return moe_apply_gather(cfg, p, x)
-    return moe_apply_sort_scatter(cfg, p, x)
+def moe_apply(cfg, p: dict, x: jax.Array, dropless: bool = False,
+              protocol=None, rng=None):
+    """x: (B, S, d) -> (out (B, S, d), aux_loss scalar).
+
+    ``dropless`` routes every token to all of its top-k experts (serving);
+    otherwise per-sequence capacity bounds the dispatch (training).  With
+    a ``protocol`` the shared expert's worker partials pool through the
+    channel (``mlp_apply(protocol=, rng=)``) and the return grows a third
+    element, that call's accounting (``None`` without a shared expert).
+    """
+    if dropless:
+        obs.count(PATH_COUNTER + "dropless")
+        y, aux = _moe_dropless(cfg, p, x)
+    else:
+        obs.count(PATH_COUNTER + "capacity")
+        if cfg.moe_impl == "gather":
+            y, aux = moe_apply_gather(cfg, p, x)
+        else:
+            y, aux = moe_apply_sort_scatter(cfg, p, x)
+    acct = None
+    if cfg.moe_shared_expert:
+        if protocol is None:
+            y = y + mlp.mlp_apply(cfg, p["shared"], x)
+        else:
+            ys, acct = mlp.mlp_apply(cfg, p["shared"], x, protocol=protocol,
+                                     rng=rng)
+            y = y + ys
+    return (y, aux) if protocol is None else (y, aux, acct)
+
+
+def _moe_dropless(cfg, p: dict, x: jax.Array
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """Routed experts of every token, no capacity: (token, expert) rows
+    sorted by expert, one ragged matmul per expert weight."""
+    b, s, d = x.shape
+    e, k = cfg.n_experts, cfg.experts_per_token
+    dt = cfg.dtype
+    xt = x.reshape(b * s, d).astype(dt)
+    with jax.named_scope("moe.route"):
+        idx, w = select(cfg, router_scores(cfg, p, xt), p.get("select_bias"))
+        flat = idx.reshape(-1)                           # (T*k,)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+        rows = jnp.take(xt, order // k, axis=0)          # (T*k, d)
+    with jax.named_scope("moe.experts"):
+        gate = jax.lax.ragged_dot(rows, p["w_gate"].astype(dt), sizes)
+        up = jax.lax.ragged_dot(rows, p["w_up"].astype(dt), sizes)
+        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+                                 p["w_down"].astype(dt), sizes)
+    with jax.named_scope("moe.route"):
+        out = jnp.take(out, jnp.argsort(order), axis=0)  # back to (t, k)
+        y = jnp.einsum("tkd,tk->td", out.reshape(b * s, k, d).astype(
+            jnp.float32), w).astype(dt)
+    return y.reshape(b, s, d), jnp.zeros((), jnp.float32)
 
 
 def moe_apply_sort_scatter(cfg, p: dict, x: jax.Array
@@ -91,11 +189,10 @@ def moe_apply_sort_scatter(cfg, p: dict, x: jax.Array
     cap = _capacity(cfg, s)
     dt = cfg.dtype
 
-    logits = (x.astype(jnp.float32) @ p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)              # (B, S, E)
-
+    probs = router_scores(cfg, p, x)                     # (B, S, E)
+    bias = p.get("select_bias")
     e_s, pos, t_s, w_s = jax.vmap(
-        lambda pr: _route_one_seq(cfg, pr, cap))(probs)  # each (B, S*k)
+        lambda pr: _route_one_seq(cfg, pr, cap, bias))(probs)  # (B, S*k)
 
     # dispatch: (B, S, d) -> (B, E, cap, d); OOB pos rows are dropped
     def scatter_one(xb, eb, pb, tb):
@@ -122,9 +219,6 @@ def moe_apply_sort_scatter(cfg, p: dict, x: jax.Array
 
     y = jax.vmap(gather_one)(out_buf, e_s, pos, t_s, w_s)
     y = constrain(y, ("batch", "seq", "embed"))
-
-    if cfg.moe_shared_expert:
-        y = y + mlp.mlp_apply(cfg, p["shared"], x)
 
     # load-balance aux loss (Switch): E * sum_e f_e * P_e
     me = jnp.mean(probs, axis=(0, 1))                    # (E,)
@@ -153,11 +247,10 @@ def moe_apply_gather(cfg, p: dict, x: jax.Array
     cap = _capacity(cfg, s)
     dt = cfg.dtype
 
-    logits = (x.astype(jnp.float32) @ p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-
+    probs = router_scores(cfg, p, x)
+    bias = p.get("select_bias")
     e_s, pos, t_s, w_s = jax.vmap(
-        lambda pr: _route_one_seq(cfg, pr, cap))(probs)  # each (B, S*k)
+        lambda pr: _route_one_seq(cfg, pr, cap, bias))(probs)  # (B, S*k)
 
     # slot->token inverse map + slot weights (tiny int/float buffers)
     def invert(eb, pb, tb, wb):
@@ -194,9 +287,6 @@ def moe_apply_gather(cfg, p: dict, x: jax.Array
 
     y = jax.vmap(combine_one)(out_buf, tok_of)
     y = constrain(y, ("batch", "seq", "embed"))
-
-    if cfg.moe_shared_expert:
-        y = y + mlp.mlp_apply(cfg, p["shared"], x)
 
     me = jnp.mean(probs, axis=(0, 1))
     dispatch_frac = jnp.zeros((e,), jnp.float32).at[e_s.reshape(-1)].add(

@@ -17,7 +17,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models import attention, fusion, layers, mamba, mlp, moe, ssm
+from repro.models import attention, fusion, layers, mamba, mla, mlp, moe, ssm
 from repro.parallel.sharding import Tagged, retag_stacked, constrain
 
 
@@ -30,6 +30,8 @@ def block_init(cfg, rng, mixer: str, ffn: str, cross: bool = False) -> dict:
     p: Dict[str, Any] = {"norm1": layers.norm_init(cfg, r[0])}
     if mixer in ("attn", "attn_nocausal"):
         p["mixer"] = attention.attn_init(cfg, r[1])
+    elif mixer == "mla":
+        p["mixer"] = mla.mla_init(cfg, r[1])
     elif mixer == "mamba":
         p["mixer"] = mamba.mamba_init(cfg, r[1])
     elif mixer == "mlstm":
@@ -55,6 +57,8 @@ def _apply_mixer_full(cfg, p, x, positions, mixer, enc_out):
         return attention.attn_full(cfg, p, x, positions, causal=True)
     if mixer == "attn_nocausal":
         return attention.attn_full(cfg, p, x, positions, causal=False)
+    if mixer == "mla":
+        return mla.mla_full(cfg, p, x, positions)
     if mixer == "mamba":
         return mamba.mamba_full(cfg, p, x)
     if mixer == "mlstm":
@@ -91,6 +95,8 @@ def block_cache_init(cfg, mixer: str, batch: int, max_seq: int, dtype,
     c: Dict[str, Any] = {}
     if mixer in ("attn", "attn_nocausal"):
         c["self"] = attention.init_cache(cfg, batch, max_seq, dtype)
+    elif mixer == "mla":
+        c["self"] = mla.init_cache(cfg, batch, max_seq, dtype)
     elif mixer == "mamba":
         c["self"] = mamba.init_cache(cfg, batch, dtype)
     elif mixer == "mlstm":
@@ -106,6 +112,8 @@ def block_cache_axes(cfg, mixer: str, has_cross: bool) -> dict:
     c: Dict[str, Any] = {}
     if mixer in ("attn", "attn_nocausal"):
         c["self"] = dict(attention.CACHE_AXES)
+    elif mixer == "mla":
+        c["self"] = dict(mla.CACHE_AXES)
     elif mixer == "mamba":
         c["self"] = dict(mamba.MAMBA_CACHE_AXES)
     elif mixer == "mlstm":
@@ -123,17 +131,22 @@ def block_step(cfg, p: dict, x: jax.Array, positions: jax.Array,
     """Decode step. x: (B,1,d). Returns (x, cache, aux).
 
     With a ``protocol`` the FFN's worker-partial fusion routes through the
-    simulated channel (``mlp_apply(protocol=, rng=)``) and the return grows
-    a fourth element — the channel-accounting dict of this block's fusion
-    site (``fusion.chan_zeros()`` for non-mlp FFNs; mixer fusions stay on
-    the ideal ``tp_fusion`` collective).  With ``protocol=None`` the ops
-    and the 3-tuple return are the historical path, unchanged.
+    simulated channel (``mlp_apply(protocol=, rng=)``; for an MoE FFN its
+    shared expert's) and the return grows a fourth element — the
+    channel-accounting dict of this block's fusion site
+    (``fusion.chan_zeros()`` for an FFN with no worker-factored part;
+    mixer fusions stay on the ideal ``tp_fusion`` collective).  With
+    ``protocol=None`` the 3-tuple return is the historical one.  MoE FFNs
+    route dropless (``moe_apply(dropless=True)``).
     """
     h = layers.norm_apply(cfg, p["norm1"], x)
     new_cache = dict(cache)
     if mixer in ("attn", "attn_nocausal"):
         out, new_cache["self"] = attention.attn_step(
             cfg, p["mixer"], h, positions, cache["self"])
+    elif mixer == "mla":
+        out, new_cache["self"] = mla.mla_step(cfg, p["mixer"], h, positions,
+                                              cache["self"])
     elif mixer == "mamba":
         out, new_cache["self"] = mamba.mamba_step(cfg, p["mixer"], h,
                                                   cache["self"])
@@ -164,7 +177,13 @@ def block_step(cfg, p: dict, x: jax.Array, positions: jax.Array,
             chan = fusion.chan_from_acct(acct)
     elif ffn == "moe":
         h = layers.norm_apply(cfg, p["norm2"], x)
-        y, aux = moe.moe_apply(cfg, p["ffn"], h)
+        if protocol is None:
+            y, aux = moe.moe_apply(cfg, p["ffn"], h, dropless=True)
+        else:
+            y, aux, acct = moe.moe_apply(cfg, p["ffn"], h, dropless=True,
+                                         protocol=protocol, rng=rng)
+            if acct is not None:
+                chan = fusion.chan_from_acct(acct)
         x = x + y
     if protocol is None:
         return x, new_cache, aux
@@ -175,7 +194,8 @@ def block_prefill(cfg, p: dict, x: jax.Array, positions: jax.Array,
                   mixer: str, ffn: str, max_seq: int,
                   enc_out: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, dict, jax.Array]:
-    """Full-sequence forward that also materializes the decode cache."""
+    """Full-sequence forward that also materializes the decode cache
+    (padded to ``max_seq``); MoE FFNs route dropless."""
     h = layers.norm_apply(cfg, p["norm1"], x)
     cache: Dict[str, Any] = {}
     if mixer in ("attn", "attn_nocausal"):
@@ -188,6 +208,13 @@ def block_prefill(cfg, p: dict, x: jax.Array, positions: jax.Array,
                 lambda b, new: jax.lax.dynamic_update_slice(
                     b, new, (0, 0, 0, 0)), buf, kv)
         cache["self"] = kv
+    elif mixer == "mla":
+        out, lat = mla.mla_full(cfg, p["mixer"], h, positions,
+                                return_cache=True)
+        buf = mla.init_cache(cfg, x.shape[0], max_seq, cfg.dtype)
+        cache["self"] = jax.tree.map(
+            lambda b, new: jax.lax.dynamic_update_slice(
+                b, new.astype(b.dtype), (0, 0, 0)), buf, lat)
     elif mixer == "mamba":
         out, cache["self"] = mamba.mamba_full(cfg, p["mixer"], h,
                                               return_cache=True)
@@ -213,7 +240,7 @@ def block_prefill(cfg, p: dict, x: jax.Array, positions: jax.Array,
         x = x + mlp.mlp_apply(cfg, p["ffn"], h)
     elif ffn == "moe":
         h = layers.norm_apply(cfg, p["norm2"], x)
-        y, aux = moe.moe_apply(cfg, p["ffn"], h)
+        y, aux = moe.moe_apply(cfg, p["ffn"], h, dropless=True)
         x = x + y
     return x, cache, aux
 

@@ -195,6 +195,11 @@ class ServeEngine:
         self._sites = model.channel_sites()
         self._bits_per_site = {}      # protocol id -> analytic uplink bits
         self.cache = model.cache_init(self.B, self.max_seq)
+        # each cache leaf's batch axis, as the model declares it
+        self._batch_axes = jax.tree.unflatten(
+            jax.tree.structure(self.cache),
+            [axes.index("batch") for axes in jax.tree.leaves(
+                model.cache_axes(), is_leaf=_is_axes)])
         self.positions = jnp.zeros((self.B,), jnp.int32)
         self.cur_token = jnp.zeros((self.B, 1), jnp.int32)
         self.active = np.zeros((self.B,), bool)
@@ -301,15 +306,13 @@ class ServeEngine:
         tokens = jnp.asarray(req.prompt, jnp.int32)[None]
         logits, cache1 = self._prefill(self.values, {"tokens": tokens})
         # scatter the single-request cache into the batch cache at `slot`
-        def put(batch_leaf, one_leaf):
-            # find the batch axis: the axis where sizes differ (B vs 1)
-            axis = _batch_axis(batch_leaf.shape, one_leaf.shape, self.B)
+        def put(batch_leaf, one_leaf, axis):
             idx = [slice(None)] * batch_leaf.ndim
             idx[axis] = slice(slot, slot + 1)
             return batch_leaf.at[tuple(idx)].set(
                 one_leaf.astype(batch_leaf.dtype))
 
-        self.cache = jax.tree.map(put, self.cache, cache1)
+        self.cache = jax.tree.map(put, self.cache, cache1, self._batch_axes)
         tok = jnp.argmax(logits, -1).astype(jnp.int32)[0]
         self.cur_token = self.cur_token.at[slot, 0].set(tok)
         self.positions = self.positions.at[slot].set(len(req.prompt))
@@ -462,12 +465,7 @@ def reference_tokens(model, values, requests: List[Request], max_seq: int,
     return out
 
 
-def _batch_axis(batch_shape, one_shape, b: int) -> int:
-    for i, (bs, os) in enumerate(zip(batch_shape, one_shape)):
-        if bs == b and os == 1:
-            return i
-    # fall back: first axis of size B
-    for i, bs in enumerate(batch_shape):
-        if bs == b:
-            return i
-    raise ValueError(f"no batch axis in {batch_shape} vs {one_shape}")
+def _is_axes(x) -> bool:
+    """A leaf of ``model.cache_axes()``: one tuple of axis names."""
+    return isinstance(x, tuple) and all(a is None or isinstance(a, str)
+                                        for a in x)

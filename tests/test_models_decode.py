@@ -1,7 +1,8 @@
 """Prefill + decode must reproduce the full-forward logits (teacher forcing).
 
 This validates every cache path: attention KV (incl. GQA + plain layout),
-mamba conv/ssm state, mLSTM/sLSTM state, and whisper's cross-attention cache.
+the latent MLA cache behind a leading dense layer, mamba conv/ssm state,
+mLSTM/sLSTM state, and whisper's cross-attention cache.
 """
 
 import jax
@@ -16,7 +17,8 @@ from repro.parallel.sharding import split_tree
 pytestmark = pytest.mark.slow    # end-to-end: excluded from the tier-1 CI job
 
 DECODE_ARCHS = ["glm4-9b", "qwen2.5-32b", "minicpm-2b", "xlstm-125m",
-                "jamba-1.5-large-398b", "qwen3-moe-30b-a3b"]
+                "jamba-1.5-large-398b", "qwen3-moe-30b-a3b",
+                "moonlight-16b-a3b"]
 
 
 @pytest.mark.parametrize("arch", DECODE_ARCHS)

@@ -69,6 +69,7 @@ def test_full_config_matches_assignment(arch):
         "pixtral-12b": (40, 5120, 32, 8, 14336, 131072),
         "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
         "whisper-base": (6, 512, 8, 8, 2048, 51865),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 1408, 163840),
     }[arch]
     cfg = get_config(arch)
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
