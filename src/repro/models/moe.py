@@ -16,7 +16,14 @@ Two dispatch paths over the same routing:
   every token reaches each of its top-k experts.  The (token, expert)
   rows are sorted by expert and the expert FFNs run as ragged matmuls
   (``jax.lax.ragged_dot``) over the groups, so their FLOPs scale with the
-  routed rows.
+  routed rows.  Inside a layer stack the matmuls read the layer's experts
+  straight out of the whole stacked weights (``layer=``): the stack's two
+  leading axes (L, E) merge into L*E groups, a bitcast, and the group
+  sizes hold the layer's row counts at groups [layer*E, (layer+1)*E) and
+  zero everywhere else.  No per-layer slice of the weights is made, so
+  none is copied ahead of the matmuls (a custom call takes no fused
+  slice as an operand).  XLA's kernel visits no empty group, and is told
+  to take each expert's weight as one block (``_ragged_dot``).
 * Capacity-bounded (training and the full forward): *per-sequence grouped
   dispatch*, top-k selection, a sort **within each sequence** (vmapped,
   never a global cross-shard sort) and a scatter into per-expert buffers
@@ -28,9 +35,11 @@ Two dispatch paths over the same routing:
   GSPMD emits the EP all-to-all.
 
 Each traced MoE layer counts its path in :mod:`repro.obs`
-(``moe.path.dropless`` / ``moe.path.capacity``), and its device work sits
-under the name scopes ``moe.route`` (router, top-k, sort and permutation,
-combine) and ``moe.experts`` (the routed expert matmuls).
+(``moe.path.dropless`` / ``moe.path.capacity``) and, on the dropless path,
+how its matmuls got their weights (``moe.weights.stacked``: the whole
+stack; ``moe.weights.sliced``: one layer's (E, d, f) leaves); its device
+work sits under the name scopes ``moe.route`` (router, top-k, sort and
+permutation, combine) and ``moe.experts`` (the routed expert matmuls).
 
 The FedOCS fusion law does not apply inside routed expert FFNs: an expert's
 FFN lives wholly on one shard, so there is no cross-worker partial
@@ -45,12 +54,23 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro import obs
 from repro.models import layers, mlp
 from repro.parallel.sharding import constrain
 
 PATH_COUNTER = "moe.path."
+WEIGHTS_COUNTER = "moe.weights."
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+# XLA's ragged matmul on the TPU is a Mosaic kernel whose grid walks
+# (row tile, group) pairs, skipping empty groups.  Left to itself it cuts
+# a (2048, 1408) expert weight into (512, 128) blocks: thousands of grid
+# steps a matmul, 2.6x slower on a v5e than one block per expert.  The
+# weight block is double-buffered in the kernel's scoped VMEM, hence the
+# bound on its bytes.
+ROW_TILE = 128
+WEIGHT_TILE_BYTES = 8 << 20
 
 
 def moe_init(cfg, rng) -> dict:
@@ -126,18 +146,21 @@ def _route_one_seq(cfg, probs: jax.Array, cap: int, bias=None):
 
 
 def moe_apply(cfg, p: dict, x: jax.Array, dropless: bool = False,
-              protocol=None, rng=None):
+              protocol=None, rng=None, layer=None):
     """x: (B, S, d) -> (out (B, S, d), aux_loss scalar).
 
     ``dropless`` routes every token to all of its top-k experts (serving);
-    otherwise per-sequence capacity bounds the dispatch (training).  With
-    a ``protocol`` the shared expert's worker partials pool through the
-    channel (``mlp_apply(protocol=, rng=)``) and the return grows a third
-    element, that call's accounting (``None`` without a shared expert).
+    otherwise per-sequence capacity bounds the dispatch (training).  A
+    dropless ``layer`` (an index into the stack) says that ``p``'s routed
+    expert leaves are the whole stacked weights (:func:`_moe_dropless`).
+    With a ``protocol`` the shared expert's worker partials pool through
+    the channel (``mlp_apply(protocol=, rng=)``) and the return grows a
+    third element, that call's accounting (``None`` without a shared
+    expert).
     """
     if dropless:
         obs.count(PATH_COUNTER + "dropless")
-        y, aux = _moe_dropless(cfg, p, x)
+        y, aux = _moe_dropless(cfg, p, x, layer)
     else:
         obs.count(PATH_COUNTER + "capacity")
         if cfg.moe_impl == "gather":
@@ -155,10 +178,38 @@ def moe_apply(cfg, p: dict, x: jax.Array, dropless: bool = False,
     return (y, aux) if protocol is None else (y, aux, acct)
 
 
-def _moe_dropless(cfg, p: dict, x: jax.Array
+def _ragged_dot(rows: jax.Array, w: jax.Array, sizes: jax.Array
+                ) -> jax.Array:
+    """``jax.lax.ragged_dot(rows, w, sizes)`` with the rows padded to a
+    multiple of ROW_TILE and, where a group's (K, N) weight fits
+    WEIGHT_TILE_BYTES, XLA's kernel told to take it as one block
+    (``ragged_dot_tiling``; backends without the kernel ignore it).  The
+    padded rows lie past the last group and are dropped.  The padding
+    also keeps the TPU compiler from refusing a ragged dot of a few rows
+    over some group counts (6 or 30 rows over 320 or 384 groups)."""
+    m = rows.shape[0]
+    rows = jnp.pad(rows, ((0, -m % ROW_TILE), (0, 0)))
+    k, n = w.shape[1:]
+    if k * n * w.dtype.itemsize > WEIGHT_TILE_BYTES:
+        return jax.lax.ragged_dot(rows, w, sizes)[:m]
+    with set_xla_metadata(ragged_dot_tiling=f"{ROW_TILE},{k},{n}"):
+        return jax.lax.ragged_dot(rows, w, sizes)[:m]
+
+
+def _moe_dropless(cfg, p: dict, x: jax.Array, layer=None
                   ) -> Tuple[jax.Array, jax.Array]:
     """Routed experts of every token, no capacity: (token, expert) rows
-    sorted by expert, one ragged matmul per expert weight."""
+    sorted by expert, one ragged matmul per expert weight.
+
+    Without ``layer``, ``p``'s expert leaves are one layer's, (E, d, f)
+    and (E, f, d), and the groups are the E experts.  With ``layer`` they
+    are the whole stack's, (L, E, d, f) and (L, E, f, d): each is viewed
+    as (L*E, d, f) or (L*E, f, d) (merging the leading axes is a bitcast)
+    and the ragged matmuls run over all L*E groups, the layer's row counts
+    at groups [layer*E, (layer+1)*E) and every other group empty.  The
+    rows stay sorted by expert as without ``layer``, so the layer's
+    experts are selected by the group sizes alone and read in place.
+    """
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.experts_per_token
     dt = cfg.dtype
@@ -169,11 +220,21 @@ def _moe_dropless(cfg, p: dict, x: jax.Array
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
         rows = jnp.take(xt, order // k, axis=0)          # (T*k, d)
+        if layer is not None:
+            groups = p["w_gate"].shape[0] * e
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((groups,), jnp.int32), sizes, (layer * e,))
     with jax.named_scope("moe.experts"):
-        gate = jax.lax.ragged_dot(rows, p["w_gate"].astype(dt), sizes)
-        up = jax.lax.ragged_dot(rows, p["w_up"].astype(dt), sizes)
-        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up,
-                                 p["w_down"].astype(dt), sizes)
+        w_gate, w_up, w_down = (p[n].astype(dt) for n in EXPERT_WEIGHTS)
+        if layer is None:
+            obs.count(WEIGHTS_COUNTER + "sliced")
+        else:
+            obs.count(WEIGHTS_COUNTER + "stacked")
+            w_gate, w_up, w_down = (v.reshape((-1,) + v.shape[2:])
+                                    for v in (w_gate, w_up, w_down))
+        gate = _ragged_dot(rows, w_gate, sizes)
+        up = _ragged_dot(rows, w_up, sizes)
+        out = _ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
     with jax.named_scope("moe.route"):
         out = jnp.take(out, jnp.argsort(order), axis=0)  # back to (t, k)
         y = jnp.einsum("tkd,tk->td", out.reshape(b * s, k, d).astype(

@@ -127,7 +127,7 @@ def block_cache_axes(cfg, mixer: str, has_cross: bool) -> dict:
 
 def block_step(cfg, p: dict, x: jax.Array, positions: jax.Array,
                cache: dict, mixer: str, ffn: str,
-               protocol=None, rng=None):
+               protocol=None, rng=None, layer=None):
     """Decode step. x: (B,1,d). Returns (x, cache, aux).
 
     With a ``protocol`` the FFN's worker-partial fusion routes through the
@@ -137,7 +137,9 @@ def block_step(cfg, p: dict, x: jax.Array, positions: jax.Array,
     (``fusion.chan_zeros()`` for an FFN with no worker-factored part;
     mixer fusions stay on the ideal ``tp_fusion`` collective).  With
     ``protocol=None`` the 3-tuple return is the historical one.  MoE FFNs
-    route dropless (``moe_apply(dropless=True)``).
+    route dropless (``moe_apply(dropless=True)``); a ``layer`` index says
+    that their routed expert leaves are the whole stack's
+    (:func:`stack_experts`).
     """
     h = layers.norm_apply(cfg, p["norm1"], x)
     new_cache = dict(cache)
@@ -178,10 +180,12 @@ def block_step(cfg, p: dict, x: jax.Array, positions: jax.Array,
     elif ffn == "moe":
         h = layers.norm_apply(cfg, p["norm2"], x)
         if protocol is None:
-            y, aux = moe.moe_apply(cfg, p["ffn"], h, dropless=True)
+            y, aux = moe.moe_apply(cfg, p["ffn"], h, dropless=True,
+                                   layer=layer)
         else:
             y, aux, acct = moe.moe_apply(cfg, p["ffn"], h, dropless=True,
-                                         protocol=protocol, rng=rng)
+                                         protocol=protocol, rng=rng,
+                                         layer=layer)
             if acct is not None:
                 chan = fusion.chan_from_acct(acct)
         x = x + y
@@ -192,10 +196,11 @@ def block_step(cfg, p: dict, x: jax.Array, positions: jax.Array,
 
 def block_prefill(cfg, p: dict, x: jax.Array, positions: jax.Array,
                   mixer: str, ffn: str, max_seq: int,
-                  enc_out: Optional[jax.Array] = None
+                  enc_out: Optional[jax.Array] = None, layer=None
                   ) -> Tuple[jax.Array, dict, jax.Array]:
     """Full-sequence forward that also materializes the decode cache
-    (padded to ``max_seq``); MoE FFNs route dropless."""
+    (padded to ``max_seq``); MoE FFNs route dropless (``layer`` as in
+    :func:`block_step`)."""
     h = layers.norm_apply(cfg, p["norm1"], x)
     cache: Dict[str, Any] = {}
     if mixer in ("attn", "attn_nocausal"):
@@ -240,7 +245,7 @@ def block_prefill(cfg, p: dict, x: jax.Array, positions: jax.Array,
         x = x + mlp.mlp_apply(cfg, p["ffn"], h)
     elif ffn == "moe":
         h = layers.norm_apply(cfg, p["norm2"], x)
-        y, aux = moe.moe_apply(cfg, p["ffn"], h, dropless=True)
+        y, aux = moe.moe_apply(cfg, p["ffn"], h, dropless=True, layer=layer)
         x = x + y
     return x, cache, aux
 
@@ -288,6 +293,38 @@ def stack_full(cfg, values: dict, x: jax.Array, positions: jax.Array,
     return x, aux
 
 
+def stack_experts(cfg, values: dict, plan) -> Tuple[dict, dict]:
+    """Take the routed expert weights of every MoE position out of a
+    stacked tree: ``(values without them, {pos: {leaf: whole stack}})``.
+
+    A serving stack closes over these, (L, E, d, f) and (L, E, f, d) in
+    ``cfg.dtype``, and hands them whole to each layer's ragged matmuls with
+    the layer's index (``moe._moe_dropless``); scanned as ``xs`` they would
+    be sliced, and each slice copied, once per layer.  Positions of any
+    other FFN keep every leaf in the scanned tree.
+    """
+    rest, experts = dict(values), {}
+    for i, (_, ffn) in enumerate(plan):
+        if ffn != "moe":
+            continue
+        key = f"pos{i}"
+        routed = dict(values[key]["ffn"])
+        experts[key] = {n: routed.pop(n).astype(cfg.dtype)
+                        for n in moe.EXPERT_WEIGHTS}
+        rest[key] = dict(values[key], ffn=routed)
+    return rest, experts
+
+
+def _with_experts(period_params: dict, experts: dict, key: str, layer):
+    """One position's block parameters and the ``layer`` index its MoE
+    FFN takes: the stacked expert leaves put back for a MoE position,
+    ``(params, None)`` for any other."""
+    if key not in experts:
+        return period_params[key], None
+    p = period_params[key]
+    return dict(p, ffn=dict(p["ffn"], **experts[key])), layer
+
+
 def stack_step(cfg, values: dict, x: jax.Array, positions: jax.Array,
                cache: dict, plan, protocol=None, rng=None):
     """Decode step through the whole stack; cache is scanned alongside.
@@ -299,29 +336,35 @@ def stack_step(cfg, values: dict, x: jax.Array, positions: jax.Array,
     the per-site accounting dicts accumulate in the carry.  The return then
     grows a fourth element, the summed channel-accounting dict of the whole
     stack; with ``protocol=None`` the scan structure and the 3-tuple return
-    are the historical path, unchanged op for op.
+    are the historical path, unchanged op for op.  MoE positions read their
+    routed experts from the whole stacked weights (:func:`stack_experts`),
+    the period's index riding the scan as a last xs leaf.
     """
     chan_mode = protocol is not None
+    values, experts = stack_experts(cfg, values, plan)
 
     def body(carry, xs):
+        layer = xs[-1] if experts else None
         if chan_mode:
             x, aux, chan = carry
-            period_params, period_cache, k = xs
+            period_params, period_cache, k = xs[:3]
         else:
             x, aux = carry
-            period_params, period_cache = xs
+            period_params, period_cache = xs[:2]
         new_cache = {}
         for i, (mixer, ffn) in enumerate(plan):
             key = f"pos{i}"
+            p, lyr = _with_experts(period_params, experts, key, layer)
             if chan_mode:
                 x, c, a, ch = block_step(
-                    cfg, period_params[key], x, positions, period_cache[key],
+                    cfg, p, x, positions, period_cache[key],
                     mixer, ffn, protocol=protocol,
-                    rng=jax.random.fold_in(k, i))
+                    rng=jax.random.fold_in(k, i), layer=lyr)
                 chan = fusion.chan_merge(chan, ch)
             else:
-                x, c, a = block_step(cfg, period_params[key], x, positions,
-                                     period_cache[key], mixer, ffn)
+                x, c, a = block_step(cfg, p, x, positions,
+                                     period_cache[key], mixer, ffn,
+                                     layer=lyr)
             new_cache[key] = c
             aux = aux + a
         carry = (x, aux, chan) if chan_mode else (x, aux)
@@ -333,6 +376,8 @@ def stack_step(cfg, values: dict, x: jax.Array, positions: jax.Array,
     if chan_mode:
         init = init + (fusion.chan_zeros(),)
         xs = xs + (jax.random.split(rng, n),)
+    if experts:
+        xs = xs + (jnp.arange(n),)
     if cfg.scan_layers:
         carry, new_cache = jax.lax.scan(body, init, xs)
     else:
@@ -353,28 +398,33 @@ def stack_prefill(cfg, values: dict, x: jax.Array, positions: jax.Array,
                   plan, max_seq: int,
                   enc_out: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, dict, jax.Array]:
-    """Full forward that also builds the stacked decode cache."""
+    """Full forward that also builds the stacked decode cache; MoE
+    positions read their routed experts as in :func:`stack_step`."""
+    values, experts = stack_experts(cfg, values, plan)
+    n = jax.tree.leaves(values)[0].shape[0]
+    xs = (values, jnp.arange(n)) if experts else values
 
-    def body(carry, period_params):
+    def body(carry, xs):
+        period_params, layer = xs if experts else (xs, None)
         x, aux = carry
         cache = {}
         for i, (mixer, ffn) in enumerate(plan):
             key = f"pos{i}"
-            x, c, a = block_prefill(cfg, period_params[key], x, positions,
-                                    mixer, ffn, max_seq, enc_out)
+            p, lyr = _with_experts(period_params, experts, key, layer)
+            x, c, a = block_prefill(cfg, p, x, positions,
+                                    mixer, ffn, max_seq, enc_out, layer=lyr)
             cache[key] = c
             aux = aux + a
         return (x, aux), cache
 
     if cfg.scan_layers:
         (x, aux), cache = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), values)
+            body, (x, jnp.zeros((), jnp.float32)), xs)
     else:
-        n = jax.tree.leaves(values)[0].shape[0]
         carry = (x, jnp.zeros((), jnp.float32))
         outs = []
         for i in range(n):
-            carry, c = body(carry, jax.tree.map(lambda v: v[i], values))
+            carry, c = body(carry, jax.tree.map(lambda v: v[i], xs))
             outs.append(c)
         cache = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
         x, aux = carry

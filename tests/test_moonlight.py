@@ -23,7 +23,7 @@ import pytest
 
 from repro import obs
 from repro.configs import get_config, get_reduced
-from repro.models import mla, moe
+from repro.models import mla, moe, transformer
 from repro.models import model as M
 from repro.parallel.sharding import split_tree
 from repro.protocol import Protocol
@@ -185,24 +185,196 @@ def test_selection_by_biased_scores_weighting_by_scores():
     np.testing.assert_allclose(w, [[0.9 / 1.7, 0.8 / 1.7]], rtol=1e-6)
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _sliced_model(monkeypatch, cfg):
+    """A fresh model whose stacks scan the routed experts as ``xs``, so
+    that each MoE layer's matmuls take its (E, d, f) slices (the path
+    before the stacks handed them the whole expert weights)."""
+    monkeypatch.setattr(transformer, "stack_experts",
+                        lambda cfg, values, plan: (values, {}))
+    return M.build(cfg)
+
+
+def test_whole_stack_experts_serve_the_sliced_paths_tokens(small,
+                                                           monkeypatch):
+    """The ragged matmuls over the whole stacked expert weights give the
+    per-layer slices' results: teacher-forced prefill and decode logits,
+    and every token the serve engine delivers, token for token."""
+    conf, m, values = small
+    rng = np.random.default_rng(4)
+    tokens = rng.integers(0, conf["vocab_size"], 18).astype(np.int32)
+    reqs = [Request(rid=i, prompt=rng.integers(0, conf["vocab_size"],
+                                               5 + 2 * i).astype(np.int32),
+                    max_new_tokens=9) for i in range(4)]
+    scfg = ServeConfig(batch_slots=3, max_seq=24, eos_id=-1)
+
+    def serve(model):
+        obs.reset(moe.WEIGHTS_COUNTER)
+        logits = teacher_forced(model, values, tokens, 8)
+        outs = ServeEngine(model, values, scfg).run(reqs)
+        return (logits, {r: c.tokens for r, c in outs.items()},
+                obs.counts(moe.WEIGHTS_COUNTER))
+
+    got, got_tokens, got_counts = serve(m)
+    want, want_tokens, want_counts = serve(_sliced_model(monkeypatch, m.cfg))
+    assert set(got_counts) == {moe.WEIGHTS_COUNTER + "stacked"}
+    assert set(want_counts) == {moe.WEIGHTS_COUNTER + "sliced"}
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+    assert jnp.argmax(got, -1).tolist() == jnp.argmax(want, -1).tolist()
+    assert got_tokens == want_tokens
+    assert all(len(t) == 9 for t in got_tokens.values())
+
+
+def test_rows_routed_to_the_last_layers_last_expert_all_arrive(small):
+    """A selection bias that sends every token of the last MoE layer to
+    its last expert, the last of the stack's L*E groups: prefill and
+    decode still match the reference, so no row falls off the end of the
+    group offsets."""
+    conf, m, values = small
+    ffn = values["blocks"]["pos0"]["ffn"]
+    skewed = dict(values, blocks={"pos0": dict(
+        values["blocks"]["pos0"],
+        ffn=dict(ffn, select_bias=ffn["select_bias"].at[-1, -1].set(10.0)))})
+    tokens = np.random.default_rng(5).integers(
+        0, conf["vocab_size"], 24).astype(np.int32)
+    layer = jax.tree.leaves(values["blocks"])[0].shape[0] - 1
+    h = jnp.asarray(np.random.default_rng(6).standard_normal(
+        (1, 24, conf["d_model"])), jnp.float32)
+    p = jax.tree.map(lambda v: v[layer], skewed["blocks"]["pos0"]["ffn"])
+    idx, _ = moe.select(m.cfg, moe.router_scores(m.cfg, p, h),
+                        p["select_bias"])
+    assert bool(jnp.all(jnp.any(idx == conf["n_routed_experts"] - 1, -1)))
+    got = teacher_forced(m, skewed, tokens, 16)
+    want = REF.forward(conf, skewed, tokens)[15:]
+    assert float(jnp.max(jnp.abs(got - want))) < LOGIT_TOL
+
+
+def test_tick_and_prefill_read_the_whole_expert_stacks(small):
+    """No equation of the tick or the prefill holds one layer's (E, d, f)
+    or (E, f, d) expert weights (no scan slice, no copy), and every ragged
+    matmul's right-hand operand is the whole stack's (L*E, ...)."""
+    conf, m, values = small
+    e, d, f = (conf["n_routed_experts"], conf["d_model"],
+               conf["moe_intermediate_size"])
+    n = jax.tree.leaves(values["blocks"])[0].shape[0]
+    b, s = 3, 10
+    cache = m.cache_init(b, s)
+    programs = {
+        "tick": jax.make_jaxpr(m.decode_step)(
+            values, jnp.zeros((b, 1), jnp.int32), jnp.zeros((b,), jnp.int32),
+            cache),
+        "prefill": jax.make_jaxpr(lambda v, t: m.prefill(
+            v, {"tokens": t}, max_seq=s))(values, jnp.zeros((1, 8),
+                                                            jnp.int32)),
+    }
+    one_layer = {(e, d, f), (e, f, d)}
+    for name, jp in programs.items():
+        eqns = list(_eqns(jp.jaxpr))
+        rhs = [eqn.invars[1].aval.shape for eqn in eqns
+               if eqn.primitive.name == "ragged_dot_general"]
+        assert rhs == [(n * e, d, f), (n * e, d, f), (n * e, f, d)], name
+        held = {v.aval.shape for eqn in eqns
+                for v in list(eqn.invars) + list(eqn.outvars)
+                if hasattr(v.aval, "shape")}
+        held |= {v.aval.shape for eqn in eqns
+                 for sub in jax.core.jaxprs_in_params(eqn.params)
+                 for v in sub.invars}
+        assert not held & one_layer, (name, held & one_layer)
+
+
+def test_weights_counters_count_each_traced_moe_layer(small):
+    """The tick and the prefill each trace the one scanned MoE position
+    on the whole-stack path; no layer takes slices."""
+    conf, m, values = small
+    obs.reset(moe.WEIGHTS_COUNTER)
+    eng = ServeEngine(m, values, ServeConfig(batch_slots=2, max_seq=12,
+                                             eos_id=-1))
+    eng.run([Request(rid=0, prompt=np.arange(4, dtype=np.int32),
+                     max_new_tokens=3)])
+    assert obs.counts(moe.WEIGHTS_COUNTER) == {
+        moe.WEIGHTS_COUNTER + "stacked": 2}
+
+
+def test_dense_tick_counts_no_expert_weights():
+    """qwen's stack has no MoE position: its tick and prefill count
+    neither weights counter, and their scans keep every FFN leaf in xs."""
+    m = M.build(get_reduced("qwen1.5-0.5b"))
+    values, _ = split_tree(jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+    obs.reset(moe.WEIGHTS_COUNTER)
+    b, s = 2, 8
+    jax.make_jaxpr(m.decode_step)(values, jnp.zeros((b, 1), jnp.int32),
+                                  jnp.zeros((b,), jnp.int32),
+                                  m.cache_init(b, s))
+    jax.make_jaxpr(lambda v, t: m.prefill(v, {"tokens": t}, max_seq=s))(
+        values, jnp.zeros((1, 4), jnp.int32))
+    assert obs.counts(moe.WEIGHTS_COUNTER) == {}
+    rest, experts = transformer.stack_experts(
+        m.cfg, values["blocks"], m.cfg.layer_plan())
+    assert experts == {} and rest == values["blocks"]
+
+
+@pytest.mark.parametrize("arch", ["qwen3-moe-30b-a3b",
+                                  "jamba-1.5-large-398b"])
+def test_scanned_and_unrolled_stacks_agree_on_the_stacked_path(arch):
+    """The unrolled stack (``scan_layers=False``) hands the MoE layers the
+    whole expert stacks as the scan does, and computes the same prefill
+    and decode."""
+    outs = {}
+    for scan in (True, False):
+        m = M.build(get_reduced(arch, scan_layers=scan))
+        values, _ = split_tree(m.init(jax.random.PRNGKey(1)))
+        tokens = jnp.asarray(np.random.default_rng(7).integers(
+            0, m.cfg.vocab_size, (2, 9)), jnp.int32)
+        obs.reset(moe.WEIGHTS_COUNTER)
+        logits, cache = m.prefill(values, {"tokens": tokens[:, :8]},
+                                  max_seq=9)
+        step, _ = m.decode_step(values, tokens[:, 8:],
+                                jnp.full((2,), 8, jnp.int32), cache)
+        counted = obs.counts(moe.WEIGHTS_COUNTER)
+        assert set(counted) == {moe.WEIGHTS_COUNTER + "stacked"}, counted
+        outs[scan] = (logits, step)
+    for a, b in zip(outs[True], outs[False]):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
 def test_expert_work_scales_with_routed_rows(small):
     """Prefill's routed experts are ragged matmuls over the T*k routed
-    rows, never a buffer of every expert over every token."""
+    rows (padded up to the kernel's row tile), never a buffer of every
+    expert over every token."""
     conf, m, values = small
-    s, k = 16, conf["num_experts_per_tok"]
+    s, k = 40, conf["num_experts_per_tok"]
     jaxpr = jax.make_jaxpr(lambda v, t: m.prefill(v, {"tokens": t}))(
         values, jnp.zeros((1, s), jnp.int32))
-    rows = []
+    rows = [eqn.invars[0].aval.shape[0] for eqn in _eqns(jaxpr.jaxpr)
+            if eqn.primitive.name == "ragged_dot_general"]
+    tile = moe.ROW_TILE
+    assert rows == [-(-s * k // tile) * tile] * 3
+    assert rows[0] < s * conf["n_routed_experts"]
 
-    def walk(jp):
-        for eqn in jp.eqns:
-            if eqn.primitive.name == "ragged_dot_general":
-                rows.append(eqn.invars[0].aval.shape[0])
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
 
-    walk(jaxpr.jaxpr)
-    assert rows == [s * k] * 3
+@pytest.mark.parametrize("m,k,n", [(37, 16, 8), (128, 32, 24),
+                                   (5, 2048, 1100)],
+                         ids=["padded", "whole_tile", "over_tile_bytes"])
+def test_ragged_dot_equals_xlas_on_the_unpadded_rows(m, k, n):
+    """``moe._ragged_dot`` (rows padded to the row tile, the kernel's
+    tiling named where a group's weight fits) gives ``jax.lax.ragged_dot``
+    on the rows as they are, up to the order of a float32 sum over k; the
+    last group ends short of the rows."""
+    ks = jax.random.split(jax.random.PRNGKey(8), 2)
+    rows = jax.random.normal(ks[0], (m, k))
+    w = jax.random.normal(ks[1], (3, k, n))
+    sizes = jnp.asarray([m // 3, 0, m - m // 3 - 1], jnp.int32)
+    assert (k * n * 4 > moe.WEIGHT_TILE_BYTES) == (k == 2048)
+    np.testing.assert_allclose(moe._ragged_dot(rows, w, sizes),
+                               jax.lax.ragged_dot(rows, w, sizes), rtol=0,
+                               atol=2 * k * np.finfo(np.float32).eps)
 
 
 def test_channel_sites_count_dense_and_shared_ffns():

@@ -17,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import ocs
+from repro.models import moe
 from repro.kernels.flash_attention import flash_attention as FA
 from repro.kernels.maxpool import maxpool as MP
 from repro.kernels.ocs_contention import ocs_contention as OC
@@ -45,6 +46,7 @@ def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 @pytest.mark.parametrize("n,k", [(4, 2048), (16, 8192)],
@@ -82,3 +84,18 @@ def test_ocs_quant_encode_compiles(one_chip):
 def test_ocs_quant_decode_compiles(one_chip):
     _compile(lambda c: OQ.decode(c, 8, jnp.float32, interpret=False),
              one_chip, ((512, 1024), jnp.uint8))
+
+
+@pytest.mark.parametrize("rows", [30, 768, 6144])
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["gate_up", "down"])
+def test_moe_ragged_dot_over_the_expert_stack_compiles(one_chip, rows, k,
+                                                       n):
+    """The routed experts' ragged matmul over moonlight's whole stack of
+    5 x 64 experts, at a few admitted rows, at a decode tick's 768 and at
+    a 1,024-token prefill's 6,144: the padding to the row tile is what
+    lets the few-row case compile over 320 groups.  An expert's weight is
+    one block of the kernel."""
+    text = _compile(moe._ragged_dot, one_chip, ((rows, k), jnp.bfloat16),
+                    ((320, k, n), jnp.bfloat16), ((320,), jnp.int32))
+    assert f'ragged_dot_tiling="{moe.ROW_TILE},{k},{n}"' in text
