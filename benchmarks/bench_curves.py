@@ -6,10 +6,9 @@ the sensing-miss probability and the backoff depth.  Every ``p_miss`` lane
 of a ``bits`` value trains inside ONE compiled train step (each lane carries
 its own traced ``repro.protocol.Protocol`` pytree), and the fused scan
 driver runs the whole steps loop in ONE dispatch per ``bits`` value.  The
-run times the fused engine, times a ``CollisionAdaptiveBits``-scheduled run
-(the ``BitsSchedule`` policy hook switching backoff depth per round from
-observed collision telemetry, still one dispatch), and self-checks the
-engine contracts:
+run drives the plain, scheduled (``CollisionAdaptiveBits``), DP and fault
+lane programs of the engine, emits their accuracy and comm rows, and
+self-checks the engine contracts:
 
   * exactly one fused compilation AND ``<= ceil(steps/log_every) + 2``
     dispatches per ``bits`` value,
@@ -24,17 +23,14 @@ engine contracts:
     shards, ``CompressedAllReduce`` inside the fused scan) stays one
     dispatch per ``bits`` value and its MEASURED per-step DP payload bits
     equal the analytic exact-k bill — the unified uplink + DP accounting
-    lands in the emitted records (``total_comm_bits``) and BENCH json
-    (``dp_payload_bits``),
+    lands in the emitted records (``total_comm_bits``),
   * the fault-injection engine (``run_fault_curves``: Gilbert–Elliott burst
     lanes + worker dropout, ``repro.faults``) adds ZERO extra traces —
     one compile and one dispatch per ``bits`` value however many fault
     lanes ride along — and its ``FaultModel.iid`` witness lane reproduces
     the plain engine's lane 0 bit for bit.
 
-``--bench-json PATH`` (or ``bench_json_path=``) additionally emits the
-timing/dispatch numbers as ``BENCH_curves.json`` — ``benchmarks/run.py``
-writes the canonical copy at the repo root for trajectory tracking.
+It times nothing: speed is measured on the chip, by ``bench/run.py``.
 
   PYTHONPATH=src python -m benchmarks.bench_curves           # full curves
   PYTHONPATH=src python -m benchmarks.bench_curves --smoke   # CI smoke tier
@@ -45,7 +41,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import sys
-import time
 from typing import List, Optional
 
 import numpy as np
@@ -93,10 +88,8 @@ def _full_config() -> tc.CurveConfig:
 def _run_engine(ccfg: tc.CurveConfig):
     tc.reset_trace_counts()
     tc.reset_dispatch_counts()
-    t0 = time.perf_counter()
     curves = tc.run_curves(ccfg)
-    wall = time.perf_counter() - t0
-    return curves, wall, tc.trace_counts(), tc.dispatch_counts()
+    return curves, tc.trace_counts(), tc.dispatch_counts()
 
 
 def _assert_sched_matches_lanes(sched: tc.ScheduledCurveResult,
@@ -120,15 +113,13 @@ def _assert_sched_matches_lanes(sched: tc.ScheduledCurveResult,
                 "scheduled-engine parity broken: trained params diverged")
 
 
-def run(smoke: bool = False, json_path: Optional[str] = None,
-        bench_json_path: Optional[str] = None) -> List[str]:
+def run(smoke: bool = False, json_path: Optional[str] = None) -> List[str]:
     ccfg = _smoke_config() if smoke else _full_config()
     n_bits = len(ccfg.bits)
-    trained_steps = ccfg.steps * n_bits          # total steps per engine
 
     # the engine contracts are the shared repro.analysis assertions (the
     # same bounds the contract registry documents)
-    curves, wall_scan, traces_s, disp_s = _run_engine(ccfg)
+    curves, traces_s, disp_s = _run_engine(ccfg)
     analysis.assert_trace_count(traces_s["fused"], n_bits, "fused engine")
     per_bits_scan = disp_s["fused"] / n_bits
     bound = analysis.fused_dispatch_bound(ccfg.steps, ccfg.log_every)
@@ -162,9 +153,7 @@ def run(smoke: bool = False, json_path: Optional[str] = None,
     # ... and the collision-adaptive policy runs end-to-end in ONE dispatch
     schedule = CollisionAdaptiveBits(tuple(ccfg.bits))
     tc.reset_dispatch_counts()
-    t0 = time.perf_counter()
     adaptive = tc.run_scheduled_curves(ccfg, schedule)
-    wall_sched = time.perf_counter() - t0
     analysis.assert_single_dispatch(tc.dispatch_counts(), "sched",
                                     "adaptive scheduled run")
     if not set(np.unique(adaptive.bits_per_step)) <= set(ccfg.bits):
@@ -183,9 +172,7 @@ def run(smoke: bool = False, json_path: Optional[str] = None,
     car = CompressedAllReduce.topk(_DP_K_FRAC)
     tc.reset_trace_counts()
     tc.reset_dispatch_counts()
-    t0 = time.perf_counter()
     dp = tc.run_curves_dp(dcfg, car)
-    wall_dp = time.perf_counter() - t0
     traces_d, disp_d = tc.trace_counts(), tc.dispatch_counts()
     analysis.assert_trace_count(traces_d["fused_dp"], n_bits,
                                 "dp curve engine")
@@ -210,9 +197,7 @@ def run(smoke: bool = False, json_path: Optional[str] = None,
     flanes = _fault_lanes(ccfg)
     tc.reset_trace_counts()
     tc.reset_dispatch_counts()
-    t0 = time.perf_counter()
     fc = tc.run_fault_curves(ccfg, flanes)
-    wall_faults = time.perf_counter() - t0
     traces_f, disp_f = tc.trace_counts(), tc.dispatch_counts()
     analysis.assert_trace_count(traces_f["fused_faults"], n_bits,
                                 "fault curve engine")
@@ -229,12 +214,6 @@ def run(smoke: bool = False, json_path: Optional[str] = None,
     if not np.isfinite(fc.acc).all():
         raise RuntimeError("fault curve run produced non-finite accuracy")
 
-    # wall-clock includes the (cacheable) compile
-    sps_scan = trained_steps / wall_scan
-    sps_sched = ccfg.steps / wall_sched
-    sps_dp = trained_steps / wall_dp
-    sps_faults = trained_steps / wall_faults
-
     records = sim_results.summarize_curves(curves)
     dp_records = sim_results.summarize_dp_curves(dp)
     fault_records = sim_results.summarize_fault_curves(fc)
@@ -242,26 +221,22 @@ def run(smoke: bool = False, json_path: Optional[str] = None,
     rows += sim_results.dp_curve_rows(dp_records)
     rows += sim_results.fault_curve_rows(fault_records)
     rows.append(
-        f"curves/engine_scan,{wall_scan / trained_steps * 1e6:.0f},"
-        f"steps_per_sec={sps_scan:.1f};dispatches_per_bits="
+        f"curves/engine_scan,0,dispatches_per_bits="
         f"{per_bits_scan:g};compiles={traces_s['fused']}")
     rows.append(
-        f"curves/engine_sched,{wall_sched / ccfg.steps * 1e6:.0f},"
-        f"steps_per_sec={sps_sched:.1f};dispatches=1;"
+        f"curves/engine_sched,0,dispatches=1;"
         f"candidates={'|'.join(str(b) for b in ccfg.bits)};"
         f"switches={sched_switches};"
         f"final_bits={int(adaptive.bits_per_step[-1])}")
     rows.append(
-        f"curves/engine_dp,{wall_dp / trained_steps * 1e6:.0f},"
-        f"steps_per_sec={sps_dp:.1f};dispatches_per_bits={per_bits_dp:g};"
+        f"curves/engine_dp,0,dispatches_per_bits={per_bits_dp:g};"
         f"compiles={traces_d['fused_dp']};dp_shards={_DP_SHARDS};"
         f"k_frac={_DP_K_FRAC:g};"
         f"dp_payload_bits_step={dp.dp_payload_bits_step};"
         f"dp_payload_frac="
         f"{dp.dp_payload_bits_step / dp.dp_dense_bits_step:.3f}")
     rows.append(
-        f"curves/engine_faults,{wall_faults / trained_steps * 1e6:.0f},"
-        f"steps_per_sec={sps_faults:.1f};fault_lanes={len(flanes)};"
+        f"curves/engine_faults,0,fault_lanes={len(flanes)};"
         f"dispatches_per_bits={disp_f['fused_faults'] / n_bits:g};"
         f"compiles={traces_f['fused_faults']};"
         f"policy={flanes[0].policy.kind};"
@@ -280,71 +255,12 @@ def run(smoke: bool = False, json_path: Optional[str] = None,
             json.dump(records + dp_records + fault_records, f, indent=2,
                       sort_keys=True)
             f.write("\n")
-    if bench_json_path:
-        bench = {
-            "bench": "curves",
-            "smoke": smoke,
-            "grid": {"bits": list(ccfg.bits), "lanes": len(ccfg.p_miss),
-                     "steps": ccfg.steps, "batch": ccfg.batch,
-                     "log_every": ccfg.log_every,
-                     "n_workers": ccfg.n_workers,
-                     "embed_dim": ccfg.embed_dim},
-            "engines": {
-                "scan": {"wall_s": round(wall_scan, 3),
-                         "steps_per_sec": round(sps_scan, 2),
-                         "dispatches_per_bits": per_bits_scan,
-                         "traces_per_bits": traces_s["fused"] / n_bits},
-                "sched": {"wall_s": round(wall_sched, 3),
-                          "steps_per_sec": round(sps_sched, 2),
-                          "dispatches": 1,
-                          "candidates": list(ccfg.bits),
-                          "switches": sched_switches,
-                          "final_bits": int(adaptive.bits_per_step[-1])},
-                "dp": {"wall_s": round(wall_dp, 3),
-                       "steps_per_sec": round(sps_dp, 2),
-                       "dispatches_per_bits": per_bits_dp,
-                       "traces_per_bits": traces_d["fused_dp"] / n_bits,
-                       "dp_shards": _DP_SHARDS},
-                "faults": {"wall_s": round(wall_faults, 3),
-                           "steps_per_sec": round(sps_faults, 2),
-                           "fault_lanes": len(flanes),
-                           "dispatches_per_bits":
-                               disp_f["fused_faults"] / n_bits,
-                           "traces_per_bits":
-                               traces_f["fused_faults"] / n_bits,
-                           "policy": flanes[0].policy.kind,
-                           "iid_witness_bitwise_equal": True},
-            },
-            "dp_payload_bits": {
-                "k_frac": _DP_K_FRAC,
-                "per_step": dp.dp_payload_bits_step,
-                "dense_per_step": dp.dp_dense_bits_step,
-                "payload_frac": round(
-                    dp.dp_payload_bits_step / dp.dp_dense_bits_step, 4),
-                "run_total": int(dp.dp_payload_bits_total.max()),
-                "measured_equals_analytic": True,
-            },
-            "parity_bitwise": True,          # FixedBits sched == plain run
-            "p0_matches_ideal": True,
-        }
-        with open(bench_json_path, "w") as f:
-            json.dump(bench, f, indent=2, sort_keys=True)
-            f.write("\n")
     return rows
 
 
 if __name__ == "__main__":
     argv = sys.argv[1:]
-    bench_json = None
-    if "--bench-json" in argv:
-        i = argv.index("--bench-json")
-        if i + 1 >= len(argv) or argv[i + 1].startswith("--"):
-            sys.exit("usage: bench_curves [--smoke] [--bench-json PATH] "
-                     "[records.json]")
-        bench_json = argv[i + 1]
-        argv = argv[:i] + argv[i + 2:]
     positional = [a for a in argv if a != "--smoke"]
     for r in run(smoke="--smoke" in argv,
-                 json_path=positional[0] if positional else None,
-                 bench_json_path=bench_json):
+                 json_path=positional[0] if positional else None):
         print(r)

@@ -13,10 +13,9 @@ Prints ``name,us_per_call,derived`` CSV rows.
   bench_roofline  roofline terms per (arch x shape) from dry-run artifacts
 
 Full (non ``--fast``) runs additionally persist their numbers as canonical
-``BENCH_*.json`` files at the repo root (``BENCH_curves.json``,
-``BENCH_serve.json``, ``BENCH_contention.json``), so the perf trajectory
-is diffable across PRs; ``--fast`` leaves the committed full-scale numbers
-untouched.
+``BENCH_*.json`` files at the repo root (``BENCH_serve.json``,
+``BENCH_contention.json``); ``--fast`` leaves the committed full-scale
+numbers untouched.
 """
 
 from __future__ import annotations
@@ -41,13 +40,10 @@ def main() -> None:
         print(row)
     for row in bench_sweep.run(smoke=fast):
         print(row)
-    # canonical trajectory files only from full-scale runs: a --fast smoke
-    # must not overwrite the committed 600-step numbers with 24-step ones
-    for row in bench_curves.run(
-            smoke=fast,
-            bench_json_path=None if fast
-            else str(REPO_ROOT / "BENCH_curves.json")):
+    for row in bench_curves.run(smoke=fast):
         print(row)
+    # canonical trajectory files only from full-scale runs: a --fast smoke
+    # must not overwrite the committed full-scale numbers with smoke ones
     for row in bench_serve.run(
             smoke=fast,
             bench_json_path=None if fast

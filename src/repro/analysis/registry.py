@@ -9,8 +9,9 @@ and which checks apply.  The :attr:`Contract.build` thunk materializes the
 actual traceable function + argument factory lazily — builders import the
 subsystem locally and construct arguments from ``ShapeDtypeStruct``/
 ``jax.eval_shape`` stand-ins, so checking a contract never executes a real
-training or serving step (tiny host constants like PRNG key data and log
-slot maps are the only concrete arrays involved).
+training or serving step.  The curve engines take the inputs of their
+runner's first dispatch at a tiny config (data and init, made on the host;
+:func:`_curve_entry`), so a contract checks what the runner dispatches.
 
 Adding a contract for a new entry point::
 
@@ -112,83 +113,49 @@ def _tiny_curve_config():
                        log_every=2)
 
 
-def _curve_args(ccfg, per_bits, logged):
-    """Abstract-aval argument factory shared by both curve engines."""
-    from repro.core import vertical
+def _curve_entry(job, ccfg, perturb) -> Entry:
+    """A curve engine's entry from its runner's own first dispatch (the
+    jitted engine and its inputs at the tiny config): ``perturb(lane, p)``
+    rebinds the program's lane arguments by name."""
     from repro.sim import train_curves as tc
 
-    vcfg_n, opt = per_bits[0], per_bits[2]
-    params0 = jax.eval_shape(lambda k: vertical.init(vcfg_n, k),
-                             jax.random.PRNGKey(0))
-    opt0 = jax.eval_shape(opt.init, params0)
-    patch_dim = (ccfg.hw // ccfg.grid) ** 2
-    sds = jax.ShapeDtypeStruct
-    views = sds((ccfg.n_workers, ccfg.n_train, patch_dim), jnp.float32)
-    labels = sds((ccfg.n_train,), jnp.int32)
-    vviews = sds((ccfg.n_workers, ccfg.n_val, patch_dim), jnp.float32)
-    vlabels = sds((ccfg.n_val,), jnp.int32)
-    slots = tc._log_slots(ccfg, logged)
-    lane_keys, k_data = _key_data(len(ccfg.p_miss)), _key_data()
+    fused, params0, opt0, lane, shared = next(tc._dispatches(ccfg, job))
 
     def argsf(p):
-        lanes = np.asarray([0.0, p], np.float32)
-        return (params0, opt0, lane_keys, lanes, k_data, views, labels,
-                vviews, vlabels, slots)
+        return (params0, opt0, *{**lane, **perturb(lane, p)}.values(),
+                *shared)
 
-    return argsf
+    return Entry(fn=fused, argsf=argsf)
+
+
+def _rebind_p(lane, p):
+    return {"p": np.asarray([0.0, p], np.float32)}
 
 
 def _build_curves_fused() -> Entry:
     from repro.sim import train_curves as tc
 
     ccfg = _tiny_curve_config()
-    per_bits = tc._make_steps(ccfg, 8)
-    logged = ccfg.logged_steps()
-    fused = tc._make_fused(ccfg, per_bits, len(logged), n_dev=1)
-    return Entry(fn=fused, argsf=_curve_args(ccfg, per_bits, logged))
+    return _curve_entry(tc._plain_job(ccfg, 1), ccfg, _rebind_p)
 
 
 def _build_curves_fused_dp() -> Entry:
     from repro.optim.compressed_allreduce import CompressedAllReduce
-    from repro.core import vertical
     from repro.sim import train_curves as tc
 
     ccfg = dataclasses.replace(_tiny_curve_config(), dp_shards=2)
-    compress = CompressedAllReduce.topk(0.25)
-    per_bits = tc._make_steps(ccfg, 8)
-    logged = ccfg.logged_steps()
-    fused = tc._make_fused_dp(ccfg, compress, per_bits, len(logged),
-                              n_s=1, n_d=1)
 
-    vcfg_n, opt = per_bits[0], per_bits[2]
-    params0 = jax.eval_shape(lambda k: vertical.init(vcfg_n, k),
-                             jax.random.PRNGKey(0))
-    opt0 = jax.eval_shape(opt.init, params0)
-    patch_dim = (ccfg.hw // ccfg.grid) ** 2
-    sds = jax.ShapeDtypeStruct
-    views = sds((ccfg.n_workers, ccfg.n_train, patch_dim), jnp.float32)
-    labels = sds((ccfg.n_train,), jnp.int32)
-    vviews = sds((ccfg.n_workers, ccfg.n_val, patch_dim), jnp.float32)
-    vlabels = sds((ccfg.n_val,), jnp.int32)
-    slots = tc._log_slots(ccfg, logged)
-    lane_keys, k_data = _key_data(len(ccfg.p_miss)), _key_data()
-    shard_ids = np.arange(ccfg.dp_shards, dtype=np.int32)
-    lanes = len(ccfg.p_miss)
-
-    def argsf(p):
+    def perturb(lane, p):
         # the perturbation lands in BOTH rebindable state leaves: the lane
         # p_miss axis AND the error-feedback memory values — the EF carry
         # must be ordinary traced data, never a recompile trigger (concrete
         # arrays here, so differing values would show up as differing
         # jaxprs if they were ever baked in)
-        p_lanes = np.asarray([0.0, p], np.float32)
-        err0 = jax.tree.map(
-            lambda x: np.full((lanes, ccfg.dp_shards) + tuple(x.shape), p,
-                              np.float32), params0)
-        return (params0, opt0, err0, lane_keys, p_lanes, shard_ids, k_data,
-                views, labels, vviews, vlabels, slots)
+        return {**_rebind_p(lane, p), "err0": jax.tree.map(
+            lambda x: np.full(x.shape, p, np.float32), lane["err0"])}
 
-    return Entry(fn=fused, argsf=argsf)
+    job = tc._dp_job(ccfg, CompressedAllReduce.topk(0.25), 1)
+    return _curve_entry(job, ccfg, perturb)
 
 
 def _build_curves_sched() -> Entry:
@@ -196,11 +163,8 @@ def _build_curves_sched() -> Entry:
     from repro.sim import train_curves as tc
 
     ccfg = _tiny_curve_config()
-    schedule = CollisionAdaptiveBits((8, 16))
-    per_cand = [tc._make_steps(ccfg, b) for b in schedule.candidates]
-    logged = ccfg.logged_steps()
-    fused = tc._make_sched_fused(ccfg, schedule, per_cand, len(logged))
-    return Entry(fn=fused, argsf=_curve_args(ccfg, per_cand[0], logged))
+    job = tc._sched_job(ccfg, CollisionAdaptiveBits((8, 16)))
+    return _curve_entry(job, ccfg, _rebind_p)
 
 
 def _build_serve_tick() -> Entry:
@@ -274,30 +238,13 @@ def _build_faults_aggregate() -> Entry:
 
 def _build_curves_fused_faults() -> Entry:
     from repro import faults
-    from repro.core import vertical
     from repro.sim import train_curves as tc
 
     ccfg = _tiny_curve_config()
-    lanes = 2
-    per_bits = tc._make_fault_steps(ccfg, 8)
-    logged = ccfg.logged_steps()
-    fused = tc._make_fused_faults(ccfg, per_bits, len(logged))
+    lanes, n = 2, ccfg.n_workers
+    policy = faults.DegradePolicy.stale()
 
-    vcfg_n = per_bits[0]
-    params0 = jax.eval_shape(lambda k: vertical.init(vcfg_n, k),
-                             jax.random.PRNGKey(0))
-    opt0 = jax.eval_shape(per_bits[1].init, params0)
-    patch_dim = (ccfg.hw // ccfg.grid) ** 2
-    sds = jax.ShapeDtypeStruct
-    views = sds((ccfg.n_workers, ccfg.n_train, patch_dim), jnp.float32)
-    labels = sds((ccfg.n_train,), jnp.int32)
-    vviews = sds((ccfg.n_workers, ccfg.n_val, patch_dim), jnp.float32)
-    vlabels = sds((ccfg.n_val,), jnp.int32)
-    slots = tc._log_slots(ccfg, logged)
-    lane_keys, k_data = _key_data(lanes), _key_data()
-    n = ccfg.n_workers
-
-    def argsf(p):
+    def perturb(lane, p):
         # lane-stacked fault grid: both lanes' GE transition probs, dropout
         # rates AND the carried chain state (bad/offline masks, stale
         # cache) move with p — the fused engine must hold at one trace
@@ -308,7 +255,7 @@ def _build_curves_fused_faults() -> Entry:
             p_miss_bad=np.asarray([0.5, 0.4 + p], np.float32),
             p_drop=np.asarray([0.0, p], np.float32),
             p_recover=np.asarray([1.0, 1.0 - p], np.float32),
-            policy=faults.DegradePolicy.stale())
+            policy=policy)
         fs0 = faults.FaultState(
             bad=np.zeros((lanes, n), bool),
             offline=(np.arange(lanes * n).reshape(lanes, n) % 3
@@ -317,10 +264,11 @@ def _build_curves_fused_faults() -> Entry:
                           np.float32),
             age=np.zeros((lanes,), np.int32),
             consec=np.zeros((lanes,), np.int32))
-        return (params0, opt0, lane_keys, fm, fs0, k_data, views, labels,
-                vviews, vlabels, slots)
+        return {"fm": fm, "fs0": fs0}
 
-    return Entry(fn=fused, argsf=argsf)
+    job = tc._fault_job(ccfg, [faults.FaultModel.iid(0.0, policy=policy)]
+                        * lanes)
+    return _curve_entry(job, ccfg, perturb)
 
 
 def _build_sweep_noisy() -> Entry:

@@ -1,62 +1,39 @@
 """Channel-in-the-loop training curves: accuracy vs channel quality.
 
-This is the paper's actual end-to-end experiment, which the repo previously
-validated only in halves: ``repro.sim.sweep`` measured protocol behaviour
-while ``repro.train`` trained with ideal pooling.  Here the two meet — the
-vertical learner's forward pass fuses embeddings through the *simulated* OCS
-channel (``repro.protocol.Protocol.ocs``: quantized D-bit contention,
-per-sub-slot miss detection, lowest-index capture), and short training runs
-sweep the ``p_miss x bits`` scenario grid into accuracy-vs-p_miss and
-accuracy-vs-bits tables (emitted by ``repro.sim.results``).
+The paper's end-to-end experiment: the vertical learner fuses worker
+embeddings through the *simulated* OCS channel (``Protocol.ocs``), and
+short training runs sweep the ``p_miss x bits`` grid into accuracy tables
+(``repro.sim.results``).
 
-Compilation contract (mirrors the sweep engine): the protocol's ``p_miss``
-leaf and the sensing rng are *traced* — the whole miss-probability axis
-trains as ``vmap`` lanes of ONE compiled train step per ``bits`` value,
-each lane carrying its own ``Protocol`` pytree (same static metadata, its
-own ``p_miss`` leaf).  An ideal ``Protocol.ideal_max(bits)`` reference run
-(same init, same data stream) trains alongside; the ``p_miss=0`` lane must
-match it bit for bit, which ``benchmarks/bench_curves.py`` and
-``tests/test_train_curves.py`` assert.
+One engine runs every curve.  Its skeleton (:func:`_lanes`, :func:`_engine`)
+builds ONE jitted dispatch per ``bits`` value: a ``lax.scan`` over the
+steps that draws each batch on device, folds the step into every lane's
+sensing key, keeps the logged series in ``(lanes, n_logged)`` histories and
+sums the counted ones, then evaluates channel-in-the-loop under fresh keys.
+On several devices the lane (and DP rank) axis runs under ``shard_map``,
+its specs derived from the arguments that carry it.  Four lane programs
+(:class:`_Program`) fill the skeleton in:
 
-The fused on-device engine drives everything: the whole ``steps`` loop is
-one ``lax.scan`` inside ONE jitted dispatch per ``bits`` value.  Batch
-indices are drawn on device from a threaded PRNG key, the noisy lanes, the
-ideal reference and the final channel-in-the-loop evaluation all run in
-that single dispatch, and the logged losses accumulate into an on-device
-``(lanes, n_logged)`` buffer fetched once at the end — no per-step dispatch
-or host sync.  On multi-device hosts the ``p_miss`` lane axis is sharded
-over a 1-D mesh via ``repro.sim.shard`` (vmap fallback on one device,
-bit-for-bit identical either way).  (The legacy per-step ``engine="python"``
-driver was removed after its one-release parity window — the scan engine
-had been property-tested bit-for-bit against it since it landed.)
+* plain (:func:`run_curves`): lanes vmapped over the traced ``(rng,
+  Protocol)`` channel state, beside an ideal ``Protocol.ideal_max(bits)``
+  lane (the same program, one lane, no keys);
+* fault (:func:`run_fault_curves`): ``FaultModel`` lanes whose chains ride
+  the scan carry;
+* scheduled (:func:`run_scheduled_curves`): a ``BitsSchedule`` switches
+  the depth per round from the last round's accounting;
+* DP (:func:`run_curves_dp`): lanes x data-parallel ranks whose top-k
+  gradients all-reduce over ``"d"`` in the scan, payload bits measured.
 
-:func:`run_scheduled_curves` additionally threads a
-``repro.protocol.BitsSchedule`` through the same fused scan: one compiled
-training-step branch per candidate depth, ``lax.switch``-ed per round by
-the schedule's pure on-device policy consuming the protocol accounting
-(collision/round telemetry) of the previous round — channel-aware backoff
-depth scheduling in ONE host dispatch for the whole run.
-
-:func:`run_curves_dp` is the 2-D generalization: p_miss lanes x
-data-parallel batch shards, with each rank's top-k-sparsified gradients
-(``repro.optim.compressed_allreduce.CompressedAllReduce``, error feedback
-carried through the scan) all-reduced over the ``"d"`` axis *inside* the
-fused scan and the DP payload bits measured from actual kept-element
-counts — the complement of the uplink accounting, reported together by
-``repro.sim.results.summarize_dp_curves``.  The DP axis runs on a 2-D mesh
-(``repro.sim.shard.mesh_2d``) when devices allow, else on a named vmap
-axis, bit-for-bit identical either way.
-
-Compilations are observable via :func:`trace_counts`, host dispatches via
-:func:`dispatch_counts` — the fused engine costs ONE dispatch per ``bits``
-value (``fused``; ``fused_dp`` for the 2-D engine), a scheduled run ONE
-dispatch total (``sched``).
+One host runner (:func:`_dispatches`, :func:`_run`) makes the data once, and
+per dispatch the keys, the shared init and the padded lane arguments.
+:func:`trace_counts` and :func:`dispatch_counts` count compilations and
+dispatches (one per ``bits`` value; ``sched``: one per run).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -88,10 +65,8 @@ def reset_trace_counts() -> None:
 
 
 def trace_counts() -> Dict[str, int]:
-    """Times each curve engine has been traced.  One :func:`run_curves`
-    costs exactly one ``fused`` trace per ``bits`` value (one ``sched``
-    trace per :func:`run_scheduled_curves`), no matter how many ``p_miss``
-    lanes the grid has."""
+    """Times each curve engine has been traced: one per ``bits`` value (one
+    ``sched`` trace per run), however many lanes the grid has."""
     return obs.view(_TRACE, _COUNTER_KEYS)
 
 
@@ -101,15 +76,10 @@ def reset_dispatch_counts() -> None:
 
 
 def dispatch_counts() -> Dict[str, int]:
-    """Jitted-engine dispatches issued from the host by each curve driver.
-
-    The fused engine issues ONE ``fused`` dispatch per ``bits`` value
-    (train loop + ideal reference + eval, all on device); a scheduled run
-    issues ONE ``sched`` dispatch for the whole training run, every
-    candidate depth included.  ``benchmarks/bench_curves.py`` asserts the
-    ``<= ceil(steps/log_every) + 2`` per-bits bound, guarding the fused
-    call structure against falling back to per-step driving.
-    """
+    """Jitted-engine dispatches issued from the host by each curve runner:
+    ONE per ``bits`` value (``sched``: one per run).
+    ``benchmarks/bench_curves.py`` asserts the ``<= ceil(steps/log_every)
+    + 2`` per-bits bound against a fall-back to per-step driving."""
     return obs.view(_DISPATCH, _COUNTER_KEYS)
 
 
@@ -121,13 +91,10 @@ def dispatch_counts() -> Dict[str, int]:
 class CurveConfig:
     """One accuracy-vs-channel-quality experiment grid.
 
-    ``p_miss`` lanes are scalars (every worker senses equally) or length-
-    ``n_workers`` sequences (heterogeneous near/far users, e.g. from
-    ``repro.sim.scenarios.near_far_p_miss``); lanes may mix both — scalars
-    broadcast.  ``backend`` picks the noisy-contention engine of the
-    channel-in-the-loop forward pass (``"scan"`` or the fused ``"pallas"``
-    kernel; bit-for-bit interchangeable) — it becomes the static
-    ``Protocol.backend`` of every lane's protocol object.
+    ``p_miss`` lanes are scalars or length-``n_workers`` sequences (near/far
+    users, e.g. ``repro.sim.scenarios.near_far_p_miss``); scalars broadcast.
+    ``backend`` is every lane protocol's contention engine (``"scan"`` or
+    the fused ``"pallas"`` kernel, bit-for-bit interchangeable).
     """
 
     bits: Sequence[int] = (8, 16)        # backoff/payload depth axis (static)
@@ -148,9 +115,7 @@ class CurveConfig:
     seed: int = 0
     log_every: int = 10
     backend: str = "scan"                # noisy-contention engine
-    dp_shards: int = 1                   # data-parallel batch shards
-    #   (run_curves_dp: each rank trains batch/dp_shards samples and the
-    #   compressed gradients all-reduce inside the fused scan)
+    dp_shards: int = 1                   # DP ranks of run_curves_dp
 
     def __post_init__(self):
         if self.dp_shards < 1:
@@ -207,12 +172,10 @@ class CurveConfig:
 class CurveResult:
     """Stacked outcome of one curve grid.
 
-    Lane axis L == ``len(config.p_miss)``; bits axis follows
-    ``config.bits`` order.  ``*_ideal`` rows come from the reference run
-    with ideal ``Protocol.ideal_max(bits)`` pooling (a single vmap lane —
-    the ideal run is deterministic and lane-independent).  ``p_miss`` is
-    the float32 lane array the engine traces (``config.lane_p_miss()``), so
-    the reported operating points are exactly the compiled ones.
+    Lane axis L == ``len(config.p_miss)``, bits axis in ``config.bits``
+    order.  ``*_ideal`` rows come from the ideal ``Protocol.ideal_max(bits)``
+    lane.  ``p_miss`` is the float32 lane array the engine traces, so the
+    reported operating points are exactly the compiled ones.
     """
 
     config: CurveConfig
@@ -232,12 +195,10 @@ class CurveResult:
 class ScheduledCurveResult:
     """Outcome of one ``BitsSchedule``-driven curve run.
 
-    The schedule picks one candidate depth per training round from the
-    previous round's protocol accounting; ``bits_per_step`` records the
-    depth every step actually trained with (``bits_per_step[0]`` is always
-    ``schedule.candidates[schedule.init_index]``).  ``collision_frac`` is
-    the lane-mean collision fraction at the logged steps — the telemetry
-    the policy consumed.
+    ``bits_per_step`` records the depth every step trained with (the first
+    is ``schedule.candidates[schedule.init_index]``); ``collision_frac`` is
+    the lane-mean collision fraction at the logged steps, the telemetry the
+    policy consumed.
     """
 
     config: CurveConfig
@@ -256,12 +217,10 @@ class ScheduledCurveResult:
 class FaultCurveResult:
     """Outcome of one fault-injection curve grid (``run_fault_curves``).
 
-    The lane axis L indexes ``fault_lanes`` — one ``repro.faults.FaultModel``
-    per lane, all sharing one (static) ``DegradePolicy`` so the whole grid
-    compiles once.  Degradation telemetry rides beside accuracy:
-    ``stale_age`` is the staleness (frames since the last resolved frame) at
-    the logged steps, and the ``*_frames``/``retry_slots`` arrays are whole-
-    run totals billed by ``FaultAccounting``.
+    The lane axis L indexes ``fault_lanes``.  ``stale_age`` is the
+    staleness (frames since the last resolved frame) at the logged steps;
+    the ``*_frames``/``retry_slots`` arrays are whole-run totals billed by
+    ``FaultAccounting``.
     """
 
     config: CurveConfig
@@ -281,13 +240,10 @@ class FaultCurveResult:
 class DPCurveResult:
     """Outcome of one 2-D (p_miss lanes x DP shards) compressed-comms run.
 
-    The DP payload numbers are MEASURED inside the fused scan — per step,
-    the kept-element counts of every rank's exact-k masks are billed through
-    ``CompressedAllReduce.reduce``'s :class:`DPAccounting` and psum'd over
-    ranks.  ``dp_payload_bits_step`` / ``dp_dense_bits_step`` are the
-    analytic per-step totals (all ranks) the measurement must equal — the
-    tie-exact ``topk_mask`` guarantees it, and ``tests/test_dp_curves.py``
-    asserts it.
+    The DP payload bits are MEASURED inside the fused scan from the kept
+    elements of every rank's exact-k masks (``DPAccounting``).
+    ``dp_payload_bits_step`` / ``dp_dense_bits_step`` are the analytic
+    per-step totals (all ranks) the measurement must equal.
     """
 
     config: CurveConfig
@@ -305,7 +261,7 @@ class DPCurveResult:
 
 
 # ---------------------------------------------------------------------------
-# shared engine pieces: data/key streams, losses, per-bits train steps
+# shared pieces: data/key streams, the optimizer, per-bits train steps
 # ---------------------------------------------------------------------------
 
 def _lane_stack(tree, lanes: int):
@@ -328,21 +284,17 @@ def _vertical_config(ccfg: CurveConfig, bits: int, noisy: bool
         task="classification", aggregation=proto)
 
 
-def _stream_keys(ccfg: CurveConfig, bits: int):
+def _stream_keys(ccfg: CurveConfig, bits: int, lanes: int):
     """Root keys of the batch and sensing streams of one ``bits`` cell.
 
-    Every stochastic input derives from these by fixed formulas —
-    ``_batch_indices(k_data, step)`` for the shared batch stream,
-    ``fold_in(lane_keys[l], step)`` for lane ``l``'s per-step sensing key
-    (``step == steps`` is the held-out evaluation key) — so runs are
-    reproducible and a scheduled run whose schedule never switches away
-    from depth ``bits`` trains bit-for-bit the plain ``run_curves``
-    trajectory of that depth.
+    Every stochastic input derives from these: ``_batch_indices(k_data,
+    step)`` for the shared batch stream, ``fold_in(lane_keys[l], step)``
+    for lane ``l``'s sensing key (``step == steps`` evaluates), alike for
+    every program.
     """
     base = jax.random.PRNGKey(ccfg.seed + 7919 * bits)
     k_data, k_noise = jax.random.split(base)
-    lane_keys = jax.random.split(k_noise, len(ccfg.p_miss))
-    return k_data, lane_keys
+    return k_data, jax.random.split(k_noise, lanes)
 
 
 def _batch_indices(k_data, step, batch: int, n_train: int):
@@ -369,9 +321,9 @@ def _make_data(ccfg: CurveConfig):
 def _make_steps(ccfg: CurveConfig, bits: int):
     """Per-bits vertical configs, optimizer, and train-step closures.
 
-    The noisy loss takes the channel state as ``chan = (rng, protocol)`` —
-    the per-lane sensing key plus the lane's ``Protocol`` pytree (its
-    ``p_miss`` leaf is the only traced difference between lanes).
+    The noisy loss takes the channel state ``chan = (rng, protocol)``, the
+    lane's sensing key and ``Protocol`` pytree (its ``p_miss`` leaf the only
+    traced difference between lanes).  Every program trains with ``opt``.
     """
     vcfg_n = _vertical_config(ccfg, bits, noisy=True)
     vcfg_i = _vertical_config(ccfg, bits, noisy=False)
@@ -405,572 +357,273 @@ def _log_slots(ccfg: CurveConfig, logged: List[int]) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# the fused on-device engine: the whole curve run is one dispatch per bits
+# the engine skeleton: one lax.scan per lane program, one dispatch
 # ---------------------------------------------------------------------------
 
-def _make_fused(ccfg: CurveConfig, per_bits, n_logged: int, n_dev: int):
-    """Build the jitted fused engine for one ``bits`` value.
+def _stack_state(params0, opt0, lane, n):
+    return _lane_stack(params0, n), _lane_stack(opt0, n)
 
-    ``per_bits`` is that value's ``_make_steps`` tuple (shared with the
-    caller, which needs its optimizer for the init).  One dispatch runs:
-    the ``lax.scan`` over all training steps (noisy lanes vmapped over the
-    traced ``(rng, Protocol)`` channel state, batch indices drawn on
-    device), the single-lane ideal reference scan, and both
-    channel-in-the-loop evaluations.  Logged losses accumulate in carried
-    on-device buffers (scattered by the precomputed step->slot map), so
-    nothing syncs to the host until the caller fetches the results.  With
-    ``n_dev > 1`` the lane axis runs under ``shard_map`` (lane-leading args
-    sharded, data/keys replicated) — bit-for-bit the vmap path, as with
-    ``run_sweep``.
+
+def _trained(state, emits):
+    return state[0], None
+
+
+@dataclasses.dataclass(frozen=True)
+class _Program:
+    """What one kind of lane carries and does inside the fused scan.
+
+    ``args``: its dispatch arguments between ``opt0`` and ``k_data``, with
+    the mesh axes they carry (``"s"`` lanes, ``"d"`` DP ranks).
+    ``step(state, batch, rngs, lane)`` -> ``(state, metrics, emit)``:
+    ``batch`` is the step's rows (``(views, labels, indices)`` with
+    ``rows`` off), ``rngs`` the lanes' step keys, ``emit`` a per-step
+    output or None.  ``evaluate(vals, ctx, rngs, lane, vviews, vlabels)``
+    -> metrics.  ``init(params0, opt0, lane, n)`` -> the carry of ``n``
+    lanes; ``finish(state, emits)`` -> the lanes to return and what their
+    evaluation needs.  ``logs``: ``(metric, dtype, per_lane)`` series kept
+    at the logged steps; ``sums``: int32 per-lane metrics summed over the
+    run.  Returns ``(vals, *logs, *sums[, emits], acc, nll)``.
     """
-    vcfg_n, vcfg_i, _opt, step_n, step_i = per_bits
-    proto_tmpl = vcfg_n.resolve_protocol()
-    steps, batch, n_train = ccfg.steps, ccfg.batch, ccfg.n_train
 
-    def scan_lanes(step_fn, vals, opts, hist, k_data, views, labels, slots):
-        """Shared steps-scan: train ``vals`` lanes, scatter logged losses."""
+    args: Tuple[Tuple[str, str], ...]
+    step: Callable
+    evaluate: Callable
+    init: Callable = _stack_state
+    finish: Callable = _trained
+    logs: Tuple[Tuple[str, object, bool], ...] = (
+        ("loss_mean", jnp.float32, True),)
+    sums: Tuple[str, ...] = ()
+    rows: bool = True
+
+
+def _lanes(ccfg: CurveConfig, prog: _Program, n_logged: int, n_s: int = 1,
+           n_d: int = 1):
+    """One program's whole run as a function of ``(params0, opt0, *args,
+    k_data, views, labels, vviews, vlabels, slots)``, under ``shard_map``
+    if ``n_s * n_d > 1`` (every output of a sharded program leads with its
+    lanes)."""
+    steps, batch, n_train = ccfg.steps, ccfg.batch, ccfg.n_train
+    names = [name for name, _ in prog.args]
+
+    def run(params0, opt0, *args):
+        lane = dict(zip(names, args))
+        k_data, views, labels, vviews, vlabels, slots = args[len(names):]
+        keys = lane.get("lane_keys")
+        n = 1 if keys is None else keys.shape[0]    # shard-local lanes
+        state = prog.init(params0, opt0, lane, n)
+        logs = tuple(jnp.zeros((n, n_logged) if per_lane else (n_logged,),
+                               dtype) for _, dtype, per_lane in prog.logs)
+        sums = tuple(jnp.zeros((n,), jnp.int32) for _ in prog.sums)
+
         def body(carry, x):
-            vals, opts, hist = carry
+            state, logs, sums = carry
             step, slot = x
             idx = _batch_indices(k_data, step, batch, n_train)
-            b = (views[:, idx], labels[idx])
-            vals, opts, met = step_fn(vals, opts, b, step)
-            hist = hist.at[:, slot].set(met["loss_mean"], mode="drop")
-            return (vals, opts, hist), None
+            b = (views[:, idx], labels[idx]) if prog.rows else (views,
+                                                                 labels, idx)
+            rngs = None if keys is None else _fold_lanes(keys, step)
+            state, met, emit = prog.step(state, b, rngs, lane)
+            logs = tuple(
+                h.at[(slice(None),) * (h.ndim - 1) + (slot,)].set(
+                    met[name], mode="drop")
+                for h, (name, _, _) in zip(logs, prog.logs))
+            sums = tuple(t + met[name] for t, name in zip(sums, prog.sums))
+            return (state, logs, sums), emit
 
-        (vals, opts, hist), _ = jax.lax.scan(
-            body, (vals, opts, hist),
+        (state, logs, sums), emits = jax.lax.scan(
+            body, (state, logs, sums),
             (jnp.arange(steps, dtype=jnp.int32), slots))
-        return vals, opts, hist
+        vals, ctx = prog.finish(state, emits)
+        rngs = None if keys is None else _fold_lanes(keys, steps)
+        met = prog.evaluate(vals, ctx, rngs, lane, vviews, vlabels)
+        tail = () if emits is None else (emits,)
+        return (vals, *logs, *sums, *tail, met["acc"], met["nll"])
 
-    def noisy_lanes(params0, opt0, lane_keys, p, k_data, views, labels,
-                    vviews, vlabels, slots):
-        lanes = lane_keys.shape[0]          # shard-local lane count
-        vals, opts = _lane_stack(params0, lanes), _lane_stack(opt0, lanes)
-        hist = jnp.zeros((lanes, n_logged), jnp.float32)
+    if n_s * n_d == 1:
+        return run
+    mesh = "sd" if n_d > 1 else "s"
+    in_specs = ((P(), P())
+                + tuple(P(*(a for a in axes if a in mesh))
+                        for _, axes in prog.args) + (P(),) * 6)
+    out_specs = (P("s"),) * (3 + len(prog.logs) + len(prog.sums))
+    if n_d > 1:
+        return sim_shard.shard_2d(run, n_s, n_d, in_specs, out_specs)
+    return sim_shard.shard_1d(run, n_s, in_specs, out_specs)
 
-        def step_fn(vals, opts, b, step):
-            chan = (_fold_lanes(lane_keys, step), proto_tmpl.with_p_miss(p))
-            return jax.vmap(step_n, in_axes=(0, 0, None, (0, 0)))(
-                vals, opts, b, chan)
 
-        vals, _opts, hist = scan_lanes(step_fn, vals, opts, hist,
-                                       k_data, views, labels, slots)
-        eval_chan = (_fold_lanes(lane_keys, steps), proto_tmpl.with_p_miss(p))
-        met = jax.vmap(
+def _engine(ccfg: CurveConfig, name: str, progs: Sequence[_Program],
+            n_logged: int, n_s: int = 1, n_d: int = 1):
+    """The jitted dispatch of one ``bits`` value (a ``name`` trace): each
+    program takes a prefix of the first one's lane arguments, and one with
+    none (the ideal lane) runs unsharded."""
+    runs = [_lanes(ccfg, p, n_logged, n_s, n_d) if p.args
+            else _lanes(ccfg, p, n_logged) for p in progs]
+    n_args = len(progs[0].args)
+
+    def fused(params0, opt0, *args):
+        obs.count(_TRACE + name)
+        outs = tuple(run(params0, opt0, *args[:len(p.args)], *args[n_args:])
+                     for run, p in zip(runs, progs))
+        return outs if len(outs) > 1 else outs[0]
+
+    return jax.jit(fused)
+
+
+# ---------------------------------------------------------------------------
+# the lane programs
+# ---------------------------------------------------------------------------
+
+def _plain(per_bits, noisy: bool = True) -> _Program:
+    """Noisy lanes over the traced ``(rng, Protocol)`` channel state; with
+    ``noisy=False`` the ideal lane (``step_i``, one lane, no keys)."""
+    vcfg_n, vcfg_i, _opt, step_n, step_i = per_bits
+
+    if not noisy:
+        def step_ideal(state, b, rngs, lane):
+            vals, opts, met = jax.vmap(step_i, in_axes=(0, 0, None))(
+                *state, b)
+            return (vals, opts), met, None
+
+        def eval_ideal(vals, ctx, rngs, lane, vviews, vlabels):
+            return jax.vmap(
+                lambda v: vertical.loss_fn(vcfg_i, v, vviews, vlabels)[1])(
+                    vals)
+        return _Program((), step_ideal, eval_ideal)
+
+    proto = vcfg_n.resolve_protocol()
+
+    def step(state, b, rngs, lane):
+        chan = (rngs, proto.with_p_miss(lane["p"]))
+        vals, opts, met = jax.vmap(step_n, in_axes=(0, 0, None, (0, 0)))(
+            *state, b, chan)
+        return (vals, opts), met, None
+
+    def evaluate(vals, ctx, rngs, lane, vviews, vlabels):
+        chan = (rngs, proto.with_p_miss(lane["p"]))
+        return jax.vmap(
             lambda v, ch: vertical.loss_fn(vcfg_n, v, vviews, vlabels,
                                            rng=ch[0], protocol=ch[1])[1],
-            in_axes=(0, (0, 0)))(vals, eval_chan)
-        return vals, hist, met["acc"], met["nll"]
+            in_axes=(0, (0, 0)))(vals, chan)
 
-    def ideal_lanes(params0, opt0, k_data, views, labels, vviews, vlabels,
-                    slots):
-        vals, opts = _lane_stack(params0, 1), _lane_stack(opt0, 1)
-        hist = jnp.zeros((1, n_logged), jnp.float32)
-
-        def step_fn(vals, opts, b, step):
-            return jax.vmap(step_i, in_axes=(0, 0, None))(vals, opts, b)
-
-        vals, _opts, hist = scan_lanes(step_fn, vals, opts, hist,
-                                       k_data, views, labels, slots)
-        met = jax.vmap(
-            lambda v: vertical.loss_fn(vcfg_i, v, vviews, vlabels)[1])(vals)
-        return vals, hist, met["acc"], met["nll"]
-
-    noisy_engine = noisy_lanes
-    if n_dev > 1:
-        noisy_engine = sim_shard.shard_1d(
-            noisy_lanes, n_dev,
-            in_specs=(P(), P(), P("s"), P("s"), P(), P(), P(), P(), P(),
-                      P()),
-            out_specs=(P("s"), P("s"), P("s"), P("s")))
-
-    def fused(params0, opt0, lane_keys, p, k_data, views, labels, vviews,
-              vlabels, slots):
-        obs.count(_TRACE + "fused")
-        n_out = noisy_engine(params0, opt0, lane_keys, p, k_data, views,
-                             labels, vviews, vlabels, slots)
-        i_out = ideal_lanes(params0, opt0, k_data, views, labels, vviews,
-                            vlabels, slots)
-        return n_out, i_out
-
-    return jax.jit(fused)
+    return _Program((("lane_keys", "s"), ("p", "s")), step, evaluate)
 
 
-def _run_curves_scan(ccfg: CurveConfig, n_devices) -> CurveResult:
-    lanes = len(ccfg.p_miss)
-    p_lanes = ccfg.lane_p_miss()                 # float32 (L,) or (L, N)
-    n_dev = sim_shard.lane_devices(n_devices, lanes)
-    p_pad = jnp.asarray(sim_shard.pad_lanes(p_lanes, n_dev))
-
-    views_j, labels_j, vv_j, vl_j = _make_data(ccfg)
-    logged = ccfg.logged_steps()
-    slots = jnp.asarray(_log_slots(ccfg, logged))
-
-    acc = np.zeros((len(ccfg.bits), lanes), np.float64)
-    nll = np.zeros_like(acc)
-    acc_ideal = np.zeros((len(ccfg.bits),), np.float64)
-    nll_ideal = np.zeros_like(acc_ideal)
-    hist = np.zeros((len(ccfg.bits), len(logged), lanes), np.float64)
-    hist_ideal = np.zeros((len(ccfg.bits), len(logged)), np.float64)
-    noisy_params_out, ideal_params_out = [], []
-
-    for bi, bits in enumerate(ccfg.bits):
-        per_bits = _make_steps(ccfg, bits)
-        vcfg_n, opt = per_bits[0], per_bits[2]
-        k_data, lane_keys = _stream_keys(ccfg, bits)
-        keys_pad = jnp.asarray(
-            sim_shard.pad_lanes(np.asarray(lane_keys), n_dev))
-
-        # identical init + identical batch stream for noisy lanes and the
-        # ideal reference: any divergence is the channel's doing
-        params0 = vertical.init(vcfg_n, jax.random.PRNGKey(ccfg.seed))
-        opt0 = opt.init(params0)
-
-        fused = _make_fused(ccfg, per_bits, len(logged), n_dev)
-        obs.count(_DISPATCH + "fused")
-        n_out, i_out = fused(params0, opt0, keys_pad, p_pad, k_data,
-                             views_j, labels_j, vv_j, vl_j, slots)
-        vals_n, hist_n, acc_n, nll_n = n_out
-        vals_i, hist_i, acc_i, nll_i = i_out
-
-        # results come back to the host only here, after the single fused
-        # dispatch — no per-step sync anywhere above
-        acc[bi] = np.asarray(acc_n)[:lanes]
-        nll[bi] = np.asarray(nll_n)[:lanes]
-        acc_ideal[bi] = float(np.asarray(acc_i)[0])
-        nll_ideal[bi] = float(np.asarray(nll_i)[0])
-        hist[bi] = np.asarray(hist_n)[:lanes].T
-        hist_ideal[bi] = np.asarray(hist_i)[0]
-        noisy_params_out.append(
-            jax.tree.map(lambda x: x[:lanes], vals_n))
-        ideal_params_out.append(vals_i)
-
-    return CurveResult(
-        config=ccfg, p_miss=ccfg.lane_p_miss(),
-        acc=acc, nll=nll, acc_ideal=acc_ideal, nll_ideal=nll_ideal,
-        loss_history=hist, ideal_loss_history=hist_ideal,
-        logged_steps=np.asarray(logged), noisy_params=noisy_params_out,
-        ideal_params=ideal_params_out)
-
-
-# ---------------------------------------------------------------------------
-# the public runners
-# ---------------------------------------------------------------------------
-
-def run_curves(ccfg: Optional[CurveConfig] = None, *,
-               n_devices: Optional[int] = None) -> CurveResult:
-    """Train the p_miss lane axis through the simulated channel, per bits.
-
-    ``ccfg=None`` runs the default :class:`CurveConfig` grid.
-
-    For every ``bits`` value: ONE compiled train step (lane-vmapped over
-    the traced ``(rng, Protocol)`` channel state) trains all
-    miss-probability lanes simultaneously from identical inits on an
-    identical batch stream, and one ideal ``Protocol.ideal_max(bits)``
-    reference trains beside it.  Evaluation runs channel-in-the-loop as
-    well (fresh sensing keys, same ``p_miss`` lanes).  The whole run is
-    ONE host dispatch per ``bits`` value.
-
-    ``n_devices`` shards the ``p_miss`` lane axis over local devices.
-    ``None`` (the default) uses every local device; ``1`` forces the
-    single-device vmap path.  Results are identical either way — sharding
-    only changes placement (lanes are padded up to a device-count multiple
-    and the padding is dropped before results are returned).
-    """
-    return _run_curves_scan(ccfg if ccfg is not None else CurveConfig(),
-                            n_devices)
-
-
-# ---------------------------------------------------------------------------
-# the fault engine: FaultModel lanes inside the fused scan, one dispatch
-# ---------------------------------------------------------------------------
-
-def _fault_stream_keys(ccfg: CurveConfig, bits: int, lanes: int):
-    """Same key-derivation formula as :func:`_stream_keys`, lane count from
-    the fault grid: with ``lanes == len(ccfg.p_miss)`` the streams are
-    bitwise identical, which is what makes an ``FaultModel.iid(p)`` lane
-    reproduce the corresponding :func:`run_curves` noisy lane bit for bit
-    (property-tested in ``tests/test_faults.py``)."""
-    base = jax.random.PRNGKey(ccfg.seed + 7919 * bits)
-    k_data, k_noise = jax.random.split(base)
-    lane_keys = jax.random.split(k_noise, lanes)
-    return k_data, lane_keys
-
-
-def _make_fault_steps(ccfg: CurveConfig, bits: int):
-    """Per-bits config, optimizer and fault-aware train step.
-
-    The channel state is ``chan = (rng, protocol, fault, fault_state)`` —
-    the protocol template carries only static contention metadata (its
-    ``p_miss``/``online`` leaves stay ``None``; the fault model supersedes
-    them), and the evolved ``FaultState`` comes back through the metrics
-    (``metrics["fault_state"]``) to be re-carried by the engine's scan.
-    """
-    vcfg_n = _vertical_config(ccfg, bits, noisy=True)
-
-    def fault_loss(values, batch, chan, _cfg=vcfg_n):
-        bviews, blabels = batch
-        rng, proto, fm, fs = chan
-        return vertical.loss_fn(_cfg, values, bviews, blabels, rng=rng,
-                                protocol=proto, fault=fm, fault_state=fs)
-
-    warmup = max(1, ccfg.steps // 10)
-    opt = optimizers.adamw(
-        schedules.linear_warmup_cosine(ccfg.lr, warmup, ccfg.steps),
-        weight_decay=0.01)
-    step_f = make_train_step(fault_loss, opt, with_rng=True)
-    return vcfg_n, opt, step_f
-
-
-def _make_fused_faults(ccfg: CurveConfig, per_bits, n_logged: int):
-    """Build the jitted fault engine for one ``bits`` value.
-
-    Same one-dispatch shape as :func:`_make_fused`: the whole ``steps``
-    loop is one ``lax.scan``, the fault lanes are vmapped over the stacked
-    ``FaultModel`` leaves and the carried per-lane ``FaultState`` (Markov
-    burst/dropout chains persist across rounds *through the scan carry*),
-    and the degradation telemetry accumulates on device beside the loss
-    history.  Evaluation runs channel-in-the-loop under the final chain
-    state with a fresh eval-shaped stale cache.
-    """
+def _fault(ccfg: CurveConfig, per_bits) -> _Program:
+    """``FaultModel`` lanes, channel state ``(rng, protocol, fault,
+    fault_state)``; evaluated under the final chain state with a fresh
+    stale cache of the eval batch's shape."""
     from repro import faults
 
-    vcfg_n, _opt, step_f = per_bits
-    proto_tmpl = vcfg_n.resolve_protocol()
-    steps, batch, n_train = ccfg.steps, ccfg.batch, ccfg.n_train
+    vcfg_n, _vi, opt, _sn, _si = per_bits
+    proto = vcfg_n.resolve_protocol()
 
-    def fault_lanes_fn(params0, opt0, lane_keys, fm, fs0, k_data, views,
-                       labels, vviews, vlabels, slots):
-        lanes = lane_keys.shape[0]
-        vals, opts = _lane_stack(params0, lanes), _lane_stack(opt0, lanes)
-        hist = jnp.zeros((lanes, n_logged), jnp.float32)
-        stale_hist = jnp.zeros((lanes, n_logged), jnp.int32)
-        drop_tot = jnp.zeros((lanes,), jnp.int32)
-        outage_tot = jnp.zeros((lanes,), jnp.int32)
-        retry_tot = jnp.zeros((lanes,), jnp.int32)
+    def fault_loss(values, batch, chan):
+        rng, prot, fm, fs = chan
+        return vertical.loss_fn(vcfg_n, values, *batch, rng=rng,
+                                protocol=prot, fault=fm, fault_state=fs)
 
-        def body(carry, x):
-            (vals, opts, fs, hist, stale_hist, drop_tot, outage_tot,
-             retry_tot) = carry
-            step, slot = x
-            idx = _batch_indices(k_data, step, batch, n_train)
-            b = (views[:, idx], labels[idx])
-            chan = (_fold_lanes(lane_keys, step), proto_tmpl, fm, fs)
-            vals, opts, met = jax.vmap(
-                step_f, in_axes=(0, 0, None, (0, None, 0, 0)))(
-                    vals, opts, b, chan)
-            met = dict(met)
-            fs = met.pop("fault_state")
-            hist = hist.at[:, slot].set(met["loss_mean"], mode="drop")
-            stale_hist = stale_hist.at[:, slot].set(met["fault_stale_age"],
-                                                    mode="drop")
-            drop_tot = drop_tot + met["fault_dropped_frames"]
-            outage_tot = outage_tot + met["fault_outage"]
-            retry_tot = retry_tot + met["fault_retry_slots"]
-            return (vals, opts, fs, hist, stale_hist, drop_tot, outage_tot,
-                    retry_tot), None
+    step_f = make_train_step(fault_loss, opt, with_rng=True)
 
-        (vals, _opts, fs, hist, stale_hist, drop_tot, outage_tot,
-         retry_tot), _ = jax.lax.scan(
-            body, (vals, opts, fs0, hist, stale_hist, drop_tot, outage_tot,
-                   retry_tot),
-            (jnp.arange(steps, dtype=jnp.int32), slots))
+    def init(params0, opt0, lane, n):
+        return _stack_state(params0, opt0, lane, n) + (lane["fs0"],)
 
-        # evaluate under the final chain state (bursts/outages carry over)
-        # with a fresh eval-batch-shaped stale cache
-        n_val = vviews.shape[1]
-        eval_fs = faults.FaultState(
+    def step(state, b, rngs, lane):
+        vals, opts, fs = state
+        vals, opts, met = jax.vmap(
+            step_f, in_axes=(0, 0, None, (0, None, 0, 0)))(
+                vals, opts, b, (rngs, proto, lane["fm"], fs))
+        met = dict(met)
+        fs = met.pop("fault_state")
+        return (vals, opts, fs), met, None
+
+    def finish(state, emits):
+        vals, _opts, fs = state
+        lanes = fs.age.shape[0]
+        return vals, faults.FaultState(
             bad=fs.bad, offline=fs.offline,
-            stale=jnp.zeros((lanes, n_val, ccfg.embed_dim), jnp.float32),
+            stale=jnp.zeros((lanes, ccfg.n_val, ccfg.embed_dim),
+                            jnp.float32),
             age=jnp.zeros((lanes,), jnp.int32),
             consec=jnp.zeros((lanes,), jnp.int32))
-        met = jax.vmap(
+
+    def evaluate(vals, eval_fs, rngs, lane, vviews, vlabels):
+        return jax.vmap(
             lambda v, r, fm_l, fs_l: vertical.loss_fn(
-                vcfg_n, v, vviews, vlabels, rng=r, protocol=proto_tmpl,
-                fault=fm_l, fault_state=fs_l)[1],
-            in_axes=(0, 0, 0, 0))(
-                vals, _fold_lanes(lane_keys, steps), fm, eval_fs)
-        return (vals, hist, stale_hist, drop_tot, outage_tot, retry_tot,
-                met["acc"], met["nll"])
+                vcfg_n, v, vviews, vlabels, rng=r, protocol=proto,
+                fault=fm_l, fault_state=fs_l)[1])(
+                    vals, rngs, lane["fm"], eval_fs)
 
-    def fused(params0, opt0, lane_keys, fm, fs0, k_data, views, labels,
-              vviews, vlabels, slots):
-        obs.count(_TRACE + "fused_faults")
-        return fault_lanes_fn(params0, opt0, lane_keys, fm, fs0, k_data,
-                              views, labels, vviews, vlabels, slots)
-
-    return jax.jit(fused)
+    return _Program(
+        (("lane_keys", "s"), ("fm", "s"), ("fs0", "s")), step, evaluate,
+        init=init, finish=finish,
+        logs=(("loss_mean", jnp.float32, True),
+              ("fault_stale_age", jnp.int32, True)),
+        sums=("fault_dropped_frames", "fault_outage", "fault_retry_slots"))
 
 
-def run_fault_curves(ccfg: CurveConfig, fault_lanes: Sequence
-                     ) -> FaultCurveResult:
-    """Train a grid of channel-fault lanes through the fused engine.
+def _sched(schedule: BitsSchedule, per_cand) -> _Program:
+    """The plain program of each candidate depth as a ``lax.switch``
+    branch, picked by the index ``schedule.update`` carries; evaluates at
+    the depth the last step trained with."""
+    plain = [_plain(per) for per in per_cand]
 
-    ``fault_lanes`` is a sequence of ``repro.faults.FaultModel`` values —
-    e.g. a burst-length sweep — all sharing one ``DegradePolicy`` (the
-    policy is static metadata; mixed policies would need one compile each,
-    so they are rejected — run one grid per policy instead).  Every fault
-    parameter is a traced leaf: the whole grid trains as vmap lanes of ONE
-    compiled dispatch per ``bits`` value (``trace_counts()["fused_faults"]``
-    stays at one per bits no matter how many lanes), the same contract as
-    :func:`run_curves`.
+    def init(params0, opt0, lane, n):
+        return _stack_state(params0, opt0, lane, n) + (
+            schedule.init_state(), jnp.int32(schedule.init_index))
 
-    Stream derivation matches :func:`run_curves` (see
-    :func:`_fault_stream_keys`): with ``len(fault_lanes) ==
-    len(ccfg.p_miss)``, an ``FaultModel.iid(p)`` lane trains bit-for-bit
-    the ``run_curves`` noisy lane of the same ``p``.  Runs single-device
-    (vmap lanes; lane sharding can follow the ``_make_fused`` pattern when
-    fault grids outgrow one device).
-    """
-    from repro import faults
+    def step(state, b, rngs, lane):
+        vals, opts, st, idx = state
+        (vals, opts), met, _ = jax.lax.switch(
+            idx, [p.step for p in plain], (vals, opts), b, rngs,
+            {"p": lane["p"]})
+        telemetry = {
+            "collision_frac": jnp.mean(met["chan_collision_frac"]),
+            "rounds": jnp.mean(met["chan_rounds"]),
+            "correct_frac": jnp.mean(met["chan_correct_frac"]),
+        }
+        st, next_idx = schedule.update(st, telemetry)
+        return ((vals, opts, st, next_idx),
+                {"loss_mean": met["loss_mean"], **telemetry}, idx)
 
-    lanes = len(fault_lanes)
-    if lanes == 0:
-        raise ValueError("fault_lanes needs at least one FaultModel")
-    policies = {fm.policy for fm in fault_lanes}
-    if len(policies) != 1:
-        raise ValueError(
-            f"all fault lanes must share one DegradePolicy (static "
-            f"metadata — one compile per policy), got {policies}")
-    fm_stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *fault_lanes)
+    def finish(state, idx_seq):
+        return state[0], idx_seq[-1]
 
-    views_j, labels_j, vv_j, vl_j = _make_data(ccfg)
-    logged = ccfg.logged_steps()
-    slots = jnp.asarray(_log_slots(ccfg, logged))
+    def evaluate(vals, idx, rngs, lane, vviews, vlabels):
+        branches = [lambda v, r, p, ev=prog.evaluate: ev(
+            v, None, r, {"p": p}, vviews, vlabels) for prog in plain]
+        return jax.lax.switch(idx, branches, vals, rngs, lane["p"])
 
-    acc = np.zeros((len(ccfg.bits), lanes), np.float64)
-    nll = np.zeros_like(acc)
-    hist = np.zeros((len(ccfg.bits), len(logged), lanes), np.float64)
-    stale = np.zeros((len(ccfg.bits), len(logged), lanes), np.int64)
-    dropped = np.zeros((len(ccfg.bits), lanes), np.int64)
-    outages = np.zeros_like(dropped)
-    retries = np.zeros_like(dropped)
-    params_out = []
-
-    for bi, bits in enumerate(ccfg.bits):
-        per_bits = _make_fault_steps(ccfg, bits)
-        vcfg_n, opt = per_bits[0], per_bits[1]
-        k_data, lane_keys = _fault_stream_keys(ccfg, bits, lanes)
-
-        params0 = vertical.init(vcfg_n, jax.random.PRNGKey(ccfg.seed))
-        opt0 = opt.init(params0)
-        fs0 = _lane_stack(
-            faults.init_state(ccfg.n_workers,
-                              (ccfg.batch, ccfg.embed_dim)), lanes)
-
-        fused = _make_fused_faults(ccfg, per_bits, len(logged))
-        obs.count(_DISPATCH + "fused_faults")
-        (vals, hist_b, stale_b, drop_b, out_b, retry_b, acc_b,
-         nll_b) = fused(params0, opt0, jnp.asarray(lane_keys), fm_stacked,
-                        fs0, k_data, views_j, labels_j, vv_j, vl_j, slots)
-
-        acc[bi] = np.asarray(acc_b)
-        nll[bi] = np.asarray(nll_b)
-        hist[bi] = np.asarray(hist_b).T
-        stale[bi] = np.asarray(stale_b, np.int64).T
-        dropped[bi] = np.asarray(drop_b, np.int64)
-        outages[bi] = np.asarray(out_b, np.int64)
-        retries[bi] = np.asarray(retry_b, np.int64)
-        params_out.append(vals)
-
-    return FaultCurveResult(
-        config=ccfg, fault_lanes=tuple(fault_lanes),
-        acc=acc, nll=nll, loss_history=hist, stale_age=stale,
-        dropped_frames=dropped, outage_frames=outages, retry_slots=retries,
-        logged_steps=np.asarray(logged), params=params_out)
+    return _Program(
+        (("lane_keys", "s"), ("p", "s")), step, evaluate, init=init,
+        finish=finish, logs=(("loss_mean", jnp.float32, True),
+                             ("collision_frac", jnp.float32, False)))
 
 
-# ---------------------------------------------------------------------------
-# the scheduled engine: BitsSchedule inside the fused scan, one dispatch
-# ---------------------------------------------------------------------------
-
-def _make_sched_fused(ccfg: CurveConfig, schedule: BitsSchedule, per_cand,
-                      n_logged: int):
-    """Build the jitted scheduled engine (all candidate depths, one jit).
-
-    One training-step branch is compiled per candidate ``bits`` (the depth
-    is static inside each branch — it fixes code dtypes and the contention
-    scan length) and ``lax.switch`` picks the branch per round from the
-    schedule's carried index.  The schedule's ``update`` consumes the
-    round's protocol accounting (lane-mean collision fraction / rounds /
-    correctness from the train-step metrics) and emits the next round's
-    index — policy and training both stay on device.
-    """
-    steps, batch, n_train = ccfg.steps, ccfg.batch, ccfg.n_train
-    cand_bits = jnp.asarray(schedule.candidates, jnp.int32)
-
-    def make_branch(ci):
-        vcfg_n, _vi, _opt, step_n, _si = per_cand[ci]
-        proto_tmpl = vcfg_n.resolve_protocol()
-
-        def branch(vals, opts, b, rngs, p):
-            chan = (rngs, proto_tmpl.with_p_miss(p))
-            return jax.vmap(step_n, in_axes=(0, 0, None, (0, 0)))(
-                vals, opts, b, chan)
-        return branch
-
-    def make_eval_branch(ci, vviews, vlabels):
-        vcfg_n = per_cand[ci][0]
-        proto_tmpl = vcfg_n.resolve_protocol()
-
-        def branch(vals, rngs, p):
-            chan = (rngs, proto_tmpl.with_p_miss(p))
-            return jax.vmap(
-                lambda v, ch: vertical.loss_fn(vcfg_n, v, vviews, vlabels,
-                                               rng=ch[0],
-                                               protocol=ch[1])[1],
-                in_axes=(0, (0, 0)))(vals, chan)
-        return branch
-
-    branches = [make_branch(ci) for ci in range(len(schedule.candidates))]
-
-    def fused(params0, opt0, lane_keys, p, k_data, views, labels, vviews,
-              vlabels, slots):
-        obs.count(_TRACE + "sched")
-        eval_branches = [make_eval_branch(ci, vviews, vlabels)
-                         for ci in range(len(schedule.candidates))]
-        lanes = lane_keys.shape[0]
-        vals, opts = _lane_stack(params0, lanes), _lane_stack(opt0, lanes)
-        hist = jnp.zeros((lanes, n_logged), jnp.float32)
-        coll_hist = jnp.zeros((n_logged,), jnp.float32)
-        st0 = schedule.init_state()
-        idx0 = jnp.int32(schedule.init_index)
-
-        def body(carry, x):
-            vals, opts, hist, coll_hist, st, idx = carry
-            step, slot = x
-            bidx = _batch_indices(k_data, step, batch, n_train)
-            b = (views[:, bidx], labels[bidx])
-            rngs = _fold_lanes(lane_keys, step)
-            vals, opts, met = jax.lax.switch(idx, branches, vals, opts, b,
-                                             rngs, p)
-            telemetry = {
-                "collision_frac": jnp.mean(met["chan_collision_frac"]),
-                "rounds": jnp.mean(met["chan_rounds"]),
-                "correct_frac": jnp.mean(met["chan_correct_frac"]),
-            }
-            st, next_idx = schedule.update(st, telemetry)
-            hist = hist.at[:, slot].set(met["loss_mean"], mode="drop")
-            coll_hist = coll_hist.at[slot].set(
-                telemetry["collision_frac"], mode="drop")
-            return ((vals, opts, hist, coll_hist, st, next_idx),
-                    (cand_bits[idx], idx))
-
-        carry0 = (vals, opts, hist, coll_hist, st0, idx0)
-        (vals, _opts, hist, coll_hist, _st, _idx), (bits_seq, idx_seq) = \
-            jax.lax.scan(
-                body, carry0, (jnp.arange(steps, dtype=jnp.int32), slots))
-
-        # evaluate at the depth the final round actually trained with, so
-        # the reported accuracy and bits_per_step[-1] name the same
-        # operating point (the post-final-update index is never trained)
-        rngs = _fold_lanes(lane_keys, steps)
-        met = jax.lax.switch(idx_seq[-1], eval_branches, vals, rngs, p)
-        return vals, hist, coll_hist, bits_seq, met["acc"], met["nll"]
-
-    return jax.jit(fused)
-
-
-def run_scheduled_curves(ccfg: CurveConfig, schedule: BitsSchedule
-                         ) -> ScheduledCurveResult:
-    """Train the ``p_miss`` lanes with a channel-aware ``BitsSchedule``.
-
-    The backoff depth is re-chosen every round by ``schedule.update`` from
-    the previous round's protocol accounting; all candidate depths compile
-    into ONE jitted program (one ``lax.switch`` branch each) and the whole
-    run — training scan, per-round policy, final channel-in-the-loop
-    evaluation — is ONE host dispatch (``dispatch_counts()["sched"]``).
-
-    The stochastic streams derive from
-    ``_stream_keys(ccfg, candidates[init_index])``, so a schedule that
-    never leaves its initial depth ``b`` (e.g. ``FixedBits(b)``) trains
-    bit-for-bit the ``run_curves(bits=(b,))`` noisy lanes (property-tested
-    in ``tests/test_protocol.py``).  Runs single-device (vmap lanes).
-    """
-    lanes = len(ccfg.p_miss)
-    p_lanes = jnp.asarray(ccfg.lane_p_miss())
-
-    views_j, labels_j, vv_j, vl_j = _make_data(ccfg)
-    logged = ccfg.logged_steps()
-    slots = jnp.asarray(_log_slots(ccfg, logged))
-
-    per_cand = [_make_steps(ccfg, b) for b in schedule.candidates]
-    init_bits = schedule.candidates[schedule.init_index]
-    k_data, lane_keys = _stream_keys(ccfg, init_bits)
-
-    # identical init for every candidate branch: the model is depth-
-    # independent (bits only changes the fused forward), so one train state
-    # serves the whole switch
-    vcfg0, opt = per_cand[0][0], per_cand[0][2]
-    params0 = vertical.init(vcfg0, jax.random.PRNGKey(ccfg.seed))
-    opt0 = opt.init(params0)
-
-    fused = _make_sched_fused(ccfg, schedule, per_cand, len(logged))
-    obs.count(_DISPATCH + "sched")
-    vals, hist, coll_hist, bits_seq, acc, nll = fused(
-        params0, opt0, jnp.asarray(lane_keys), p_lanes, k_data, views_j,
-        labels_j, vv_j, vl_j, slots)
-
-    return ScheduledCurveResult(
-        config=ccfg, schedule=schedule, p_miss=ccfg.lane_p_miss(),
-        acc=np.asarray(acc, np.float64)[:lanes],
-        nll=np.asarray(nll, np.float64)[:lanes],
-        loss_history=np.asarray(hist, np.float64)[:lanes].T,
-        collision_frac=np.asarray(coll_hist, np.float64),
-        bits_per_step=np.asarray(bits_seq, np.int64),
-        logged_steps=np.asarray(logged), params=vals)
-
-
-# ---------------------------------------------------------------------------
-# the 2-D engine: p_miss lanes x data-parallel shards, compressed all-reduce
-# ---------------------------------------------------------------------------
-
-def _make_fused_dp(ccfg: CurveConfig, compress: CompressedAllReduce,
-                   per_bits, n_logged: int, n_s: int, n_d: int):
-    """Build the jitted 2-D engine for one ``bits`` value.
-
-    Every training step, each DP rank draws its slice of the shared batch
-    stream, runs the channel-in-the-loop forward on its own sensing key
-    (``fold_in(lane_step_key, rank)``), and the sparse gradients all-reduce
-    over the ``"d"`` axis via ``compress.reduce`` — all inside the single
-    ``lax.scan``/dispatch of the fused-engine contract.  Per-step measured
-    payload bits ride the scan carry next to the loss history.
-
-    The ``"d"`` axis is either a mesh axis (``n_d == dp_shards``, gradients
-    cross devices) or a ``vmap(axis_name="d")`` axis on one device —
-    ``compress.reduce``'s gather+fixed-order-sum makes the two bit-for-bit
-    identical (``dp_mesh_shape`` never splits the DP axis between the two).
-    Lanes shard over ``"s"`` exactly as in :func:`_make_fused`.
-    """
-    vcfg_n = per_bits[0]
-    opt = per_bits[2]
-    proto_tmpl = vcfg_n.resolve_protocol()
-    steps, batch, n_train = ccfg.steps, ccfg.batch, ccfg.n_train
-    dp_shards = ccfg.dp_shards
-    shard_b = batch // dp_shards
-    mesh_dp = n_d > 1
-
+def _dp(ccfg: CurveConfig, compress: CompressedAllReduce, per_bits,
+        mesh_dp: bool) -> _Program:
+    """p_miss lanes x DP ranks: each rank trains its slice of the batch on
+    its own key (``fold_in(lane_step_key, rank)``).  ``"d"`` is a mesh axis
+    (``mesh_dp``) or a named vmap axis, with the reduce's gather and
+    fixed-order sum the same on both."""
+    vcfg_n, _vi, opt, _sn, _si = per_bits
+    proto = vcfg_n.resolve_protocol()
+    shard_b = ccfg.batch // ccfg.dp_shards
     grad_fn = jax.value_and_grad(
         lambda v, bv, bl, rng, p_l: vertical.loss_fn(
-            vcfg_n, v, bv, bl, rng=rng,
-            protocol=proto_tmpl.with_p_miss(p_l)),
+            vcfg_n, v, bv, bl, rng=rng, protocol=proto.with_p_miss(p_l)),
         has_aux=True)
 
-    def dp_lanes(params0, opt0, err0, lane_keys, p, shard_ids, k_data,
-                 views, labels, vviews, vlabels, slots):
-        lanes = lane_keys.shape[0]          # shard-local lane count
-        d_local = shard_ids.shape[0]        # 1 on the mesh path, D vmapped
-        vals = _lane_stack(_lane_stack(params0, d_local), lanes)
-        opts = _lane_stack(_lane_stack(opt0, d_local), lanes)
-        hist = jnp.zeros((lanes, n_logged), jnp.float32)
-        pay_hist = jnp.zeros((lanes, n_logged), jnp.int32)
-        pay_total = jnp.zeros((lanes,), jnp.int32)
+    def init(params0, opt0, lane, n):
+        d_local = lane["shard_ids"].shape[0]   # 1 on the mesh, D vmapped
+        return (_lane_stack(_lane_stack(params0, d_local), n),
+                _lane_stack(_lane_stack(opt0, d_local), n), lane["err0"])
+
+    def step(state, b, rngs, lane):
+        views, labels, idx = b
+        p, shard_ids = lane["p"], lane["shard_ids"]
 
         def rank_step(vals, opts, err, shard_id, rng_lane, p_l, idx):
-            """One DP rank of one lane: local grads -> compressed all-reduce
-            over "d" -> rank-mean update.  Params/opt stay bitwise identical
-            across ranks (same reduced gradient); only ``err`` diverges."""
+            # params/opt stay bitwise alike across ranks; err diverges
             rng = jax.random.fold_in(rng_lane, shard_id)
             idx_s = jax.lax.dynamic_slice(idx, (shard_id * shard_b,),
                                           (shard_b,))
@@ -984,158 +637,264 @@ def _make_fused_dp(ccfg: CurveConfig, compress: CompressedAllReduce,
             return vals, opts, err, loss_mean, acct.payload_bits
 
         if mesh_dp:
-            # the mesh carries "d": each device holds one rank (d_local==1);
-            # only the lane axis is vmapped — collectives hit the mesh axis
-            def step_all(vals, opts, errs, rngs, idx):
-                v, o, e = (jax.tree.map(lambda x: x[:, 0], t)
-                           for t in (vals, opts, errs))
-                v, o, e, lm, pay = jax.vmap(
-                    rank_step, in_axes=(0, 0, 0, None, 0, 0, None))(
-                        v, o, e, shard_ids[0], rngs, p, idx)
-                v, o, e = (jax.tree.map(lambda x: x[:, None], t)
-                           for t in (v, o, e))
-                return v, o, e, lm, pay
+            # the mesh carries "d" (one rank a device): vmap the lanes only
+            v, o, e = (jax.tree.map(lambda x: x[:, 0], t) for t in state)
+            v, o, e, lm, pay = jax.vmap(
+                rank_step, in_axes=(0, 0, 0, None, 0, 0, None))(
+                    v, o, e, shard_ids[0], rngs, p, idx)
+            state = tuple(jax.tree.map(lambda x: x[:, None], t)
+                          for t in (v, o, e))
         else:
-            # single-device DP: the "d" axis is a named vmap axis — the
-            # collectives see the identical (D, ...) stacking order
+            # one device: "d" is a named vmap axis, the same stacking order
             ranks = jax.vmap(rank_step, in_axes=(0, 0, 0, 0, None, None,
                                                  None), axis_name="d")
+            *state, lm, pay = jax.vmap(
+                ranks, in_axes=(0, 0, 0, None, 0, 0, None))(
+                    *state, shard_ids, rngs, p, idx)
+            # per-rank outputs are rank-invariant (post-psum): take rank 0
+            state, lm, pay = tuple(state), lm[:, 0], pay[:, 0]
+        return state, {"loss_mean": lm, "payload_bits": pay}, None
 
-            def step_all(vals, opts, errs, rngs, idx):
-                v, o, e, lm, pay = jax.vmap(
-                    ranks, in_axes=(0, 0, 0, None, 0, 0, None))(
-                        vals, opts, errs, shard_ids, rngs, p, idx)
-                # per-rank outputs are rank-invariant (post-psum): take rank 0
-                return v, o, e, lm[:, 0], pay[:, 0]
+    def finish(state, emits):
+        # rank replicas are bitwise identical: return the local rank's copy
+        return jax.tree.map(lambda x: x[:, 0], state[0]), None
 
-        def body(carry, x):
-            vals, opts, errs, hist, pay_hist, pay_total = carry
-            step, slot = x
-            idx = _batch_indices(k_data, step, batch, n_train)
-            rngs = _fold_lanes(lane_keys, step)
-            vals, opts, errs, lm, pay = step_all(vals, opts, errs, rngs, idx)
-            hist = hist.at[:, slot].set(lm, mode="drop")
-            pay_hist = pay_hist.at[:, slot].set(pay, mode="drop")
-            pay_total = pay_total + pay
-            return (vals, opts, errs, hist, pay_hist, pay_total), None
-
-        carry0 = (vals, opts, err0, hist, pay_hist, pay_total)
-        (vals, _opts, _errs, hist, pay_hist, pay_total), _ = jax.lax.scan(
-            body, carry0, (jnp.arange(steps, dtype=jnp.int32), slots))
-
-        # rank replicas are bitwise identical: evaluate the local rank's copy
-        vals_l = jax.tree.map(lambda x: x[:, 0], vals)
-        eval_rngs = _fold_lanes(lane_keys, steps)
-        met = jax.vmap(
+    def evaluate(vals, ctx, rngs, lane, vviews, vlabels):
+        return jax.vmap(
             lambda v, r, p_l: vertical.loss_fn(
                 vcfg_n, v, vviews, vlabels, rng=r,
-                protocol=proto_tmpl.with_p_miss(p_l))[1],
-            in_axes=(0, 0, 0))(vals_l, eval_rngs, p)
-        return vals_l, hist, pay_hist, pay_total, met["acc"], met["nll"]
+                protocol=proto.with_p_miss(p_l))[1],
+            in_axes=(0, 0, 0))(vals, rngs, lane["p"])
 
-    dp_engine = dp_lanes
-    if n_d > 1:
-        dp_engine = sim_shard.shard_2d(
-            dp_lanes, n_s, n_d,
-            in_specs=(P(), P(), P("s", "d"), P("s"), P("s"), P("d"), P(),
-                      P(), P(), P(), P(), P()),
-            out_specs=(P("s"),) * 6)
-    elif n_s > 1:
-        dp_engine = sim_shard.shard_1d(
-            dp_lanes, n_s,
-            in_specs=(P(), P(), P("s"), P("s"), P("s"), P(), P(), P(), P(),
-                      P(), P(), P()),
-            out_specs=(P("s"),) * 6)
-
-    def fused(params0, opt0, err0, lane_keys, p, shard_ids, k_data, views,
-              labels, vviews, vlabels, slots):
-        obs.count(_TRACE + "fused_dp")
-        return dp_engine(params0, opt0, err0, lane_keys, p, shard_ids,
-                         k_data, views, labels, vviews, vlabels, slots)
-
-    return jax.jit(fused)
+    return _Program(
+        (("err0", "sd"), ("lane_keys", "s"), ("p", "s"), ("shard_ids", "d")),
+        step, evaluate, init=init, finish=finish,
+        logs=(("loss_mean", jnp.float32, True),
+              ("payload_bits", jnp.int32, True)),
+        sums=("payload_bits",), rows=False)
 
 
-def _run_curves_dp(ccfg: CurveConfig, compress: CompressedAllReduce,
-                   n_devices) -> DPCurveResult:
-    lanes = len(ccfg.p_miss)
-    p_lanes = ccfg.lane_p_miss()
-    n_s, n_d = sim_shard.dp_mesh_shape(n_devices, lanes, ccfg.dp_shards)
-    p_pad = jnp.asarray(sim_shard.pad_lanes(p_lanes, n_s))
-    l_pad = p_pad.shape[0]
-    shard_ids = jnp.arange(ccfg.dp_shards, dtype=jnp.int32)
+def _make_fused(ccfg: CurveConfig, per_bits, n_logged: int, n_dev: int):
+    """The plain engine's dispatch for one ``bits`` value, trained with the
+    steps of ``per_bits`` (that value's ``_make_steps`` tuple).  Arguments
+    ``(params0, opt0, lane_keys, p, k_data, views, labels, vviews, vlabels,
+    slots)``; outputs ``((vals, hist, acc, nll), (vals_i, hist_i, acc_i,
+    nll_i))``.  With ``n_dev > 1`` the noisy lanes shard over a 1-D mesh."""
+    return _engine(ccfg, "fused", (_plain(per_bits), _plain(per_bits, False)),
+                   n_logged, n_s=n_dev)
 
-    views_j, labels_j, vv_j, vl_j = _make_data(ccfg)
+
+# ---------------------------------------------------------------------------
+# the host runner and the public runners
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class _Job:
+    """One runner's dispatches: ``build(per_bits_list, n_logged)`` and
+    ``lane_args(lane_keys, params0)`` (in the program's order) for each of
+    ``groups``' ``(key_bits, bits)`` (default: one per ``ccfg.bits``)."""
+
+    name: str
+    lanes: int
+    build: Callable
+    lane_args: Callable
+    n_pad: int = 1
+    groups: Optional[Sequence[Tuple[int, Tuple[int, ...]]]] = None
+
+
+def _dispatches(ccfg: CurveConfig, job: _Job):
+    """Yield ``(fused, params0, opt0, lane, shared)`` per dispatch, the
+    data made once; every lane shares the init and the batch stream."""
+    views, labels, vviews, vlabels = _make_data(ccfg)
     logged = ccfg.logged_steps()
     slots = jnp.asarray(_log_slots(ccfg, logged))
+    for key_bits, bits in job.groups or [(b, (b,)) for b in ccfg.bits]:
+        per = [_make_steps(ccfg, b) for b in bits]
+        k_data, lane_keys = _stream_keys(ccfg, key_bits, job.lanes)
+        keys = jnp.asarray(sim_shard.pad_lanes(np.asarray(lane_keys),
+                                               job.n_pad))
+        params0 = vertical.init(per[0][0], jax.random.PRNGKey(ccfg.seed))
+        opt0 = per[0][2].init(params0)
+        yield (job.build(per, len(logged)), params0, opt0,
+               job.lane_args(keys, params0),
+               (k_data, views, labels, vviews, vlabels, slots))
 
-    acc = np.zeros((len(ccfg.bits), lanes), np.float64)
-    nll = np.zeros_like(acc)
-    hist = np.zeros((len(ccfg.bits), len(logged), lanes), np.float64)
-    pay = np.zeros((len(ccfg.bits), len(logged), lanes), np.int64)
-    pay_total = np.zeros((len(ccfg.bits), lanes), np.int64)
-    params_out = []
-    pay_step = dense_step = 0
 
-    for bi, bits in enumerate(ccfg.bits):
-        per_bits = _make_steps(ccfg, bits)
-        vcfg_n, opt = per_bits[0], per_bits[2]
-        k_data, lane_keys = _stream_keys(ccfg, bits)
-        keys_pad = jnp.asarray(
-            sim_shard.pad_lanes(np.asarray(lane_keys), n_s))
+def _run(ccfg: CurveConfig, job: _Job) -> List:
+    """Every dispatch of ``job``, padding dropped, outputs on the device."""
+    outs = []
+    for fused, params0, opt0, lane, shared in _dispatches(ccfg, job):
+        obs.count(_DISPATCH + job.name)
+        out = fused(params0, opt0, *lane.values(), *shared)
+        if job.n_pad > 1:
+            out = jax.tree.map(lambda x: x[:job.lanes], out)
+        outs.append(out)
+    return outs
 
-        params0 = vertical.init(vcfg_n, jax.random.PRNGKey(ccfg.seed))
-        opt0 = opt.init(params0)
+
+def _fetch(outs, i: int, dtype=np.float64, lanes_last: bool = False):
+    """Output ``i`` of every dispatch on the host, stacked over them."""
+    return np.stack([np.asarray(o[i], dtype).T if lanes_last
+                     else np.asarray(o[i], dtype) for o in outs])
+
+
+def _p_lanes(ccfg: CurveConfig, n_pad: int):
+    return jnp.asarray(sim_shard.pad_lanes(ccfg.lane_p_miss(), n_pad))
+
+
+def _plain_job(ccfg: CurveConfig, n_devices) -> _Job:
+    n_dev = sim_shard.lane_devices(n_devices, len(ccfg.p_miss))
+    p = _p_lanes(ccfg, n_dev)
+    return _Job("fused", len(ccfg.p_miss),
+                lambda per, n_logged: _make_fused(ccfg, per[0], n_logged,
+                                                  n_dev),
+                lambda keys, params0: {"lane_keys": keys, "p": p}, n_dev)
+
+
+def _fault_job(ccfg: CurveConfig, fault_lanes: Sequence) -> _Job:
+    from repro import faults
+
+    lanes = len(fault_lanes)
+    if lanes == 0:
+        raise ValueError("fault_lanes needs at least one FaultModel")
+    policies = {fm.policy for fm in fault_lanes}
+    if len(policies) != 1:
+        raise ValueError(
+            f"all fault lanes must share one DegradePolicy (static "
+            f"metadata — one compile per policy), got {policies}")
+    fm = jax.tree.map(lambda *xs: jnp.stack(xs), *fault_lanes)
+    fs0 = _lane_stack(faults.init_state(ccfg.n_workers,
+                                        (ccfg.batch, ccfg.embed_dim)), lanes)
+    return _Job("fused_faults", lanes,
+                lambda per, n_logged: _engine(
+                    ccfg, "fused_faults", (_fault(ccfg, per[0]),), n_logged),
+                lambda keys, params0: {"lane_keys": keys, "fm": fm,
+                                       "fs0": fs0})
+
+
+def _sched_job(ccfg: CurveConfig, schedule: BitsSchedule) -> _Job:
+    # one init serves every candidate branch: the model is depth-
+    # independent (bits only changes the fused forward)
+    cands = tuple(schedule.candidates)
+    p = _p_lanes(ccfg, 1)
+    return _Job("sched", len(ccfg.p_miss),
+                lambda per, n_logged: _engine(
+                    ccfg, "sched", (_sched(schedule, per),), n_logged),
+                lambda keys, params0: {"lane_keys": keys, "p": p},
+                groups=[(cands[schedule.init_index], cands)])
+
+
+def _dp_job(ccfg: CurveConfig, compress: CompressedAllReduce,
+            n_devices) -> _Job:
+    n_s, n_d = sim_shard.dp_mesh_shape(n_devices, len(ccfg.p_miss),
+                                       ccfg.dp_shards)
+    p = _p_lanes(ccfg, n_s)
+    shard_ids = jnp.arange(ccfg.dp_shards, dtype=jnp.int32)
+
+    def lane_args(keys, params0):
         # per-(lane, rank) error-feedback memory, a traced scan carry
         err0 = jax.tree.map(
-            lambda x: jnp.zeros((l_pad, ccfg.dp_shards) + x.shape,
+            lambda x: jnp.zeros((keys.shape[0], ccfg.dp_shards) + x.shape,
                                 jnp.float32), params0)
-        # the analytic per-step bill every measured step must equal
-        pay_step = compress.payload_bits(params0) * ccfg.dp_shards
-        dense_step = compress.dense_bits(params0) * ccfg.dp_shards
+        return {"err0": err0, "lane_keys": keys, "p": p,
+                "shard_ids": shard_ids}
 
-        fused = _make_fused_dp(ccfg, compress, per_bits, len(logged), n_s,
-                               n_d)
-        obs.count(_DISPATCH + "fused_dp")
-        vals, hist_b, pay_b, pay_tot_b, acc_b, nll_b = fused(
-            params0, opt0, err0, keys_pad, p_pad, shard_ids, k_data,
-            views_j, labels_j, vv_j, vl_j, slots)
+    return _Job("fused_dp", len(ccfg.p_miss),
+                lambda per, n_logged: _engine(
+                    ccfg, "fused_dp", (_dp(ccfg, compress, per[0], n_d > 1),),
+                    n_logged, n_s, n_d),
+                lane_args, n_s)
 
-        acc[bi] = np.asarray(acc_b)[:lanes]
-        nll[bi] = np.asarray(nll_b)[:lanes]
-        hist[bi] = np.asarray(hist_b)[:lanes].T
-        pay[bi] = np.asarray(pay_b, np.int64)[:lanes].T
-        pay_total[bi] = np.asarray(pay_tot_b, np.int64)[:lanes]
-        params_out.append(jax.tree.map(lambda x: x[:lanes], vals))
 
-    return DPCurveResult(
-        config=ccfg, compress=compress, p_miss=ccfg.lane_p_miss(),
-        acc=acc, nll=nll, loss_history=hist,
-        dp_payload_bits=pay, dp_payload_bits_total=pay_total,
-        dp_payload_bits_step=int(pay_step),
-        dp_dense_bits_step=int(dense_step),
-        logged_steps=np.asarray(logged), params=params_out)
+def run_curves(ccfg: Optional[CurveConfig] = None, *,
+               n_devices: Optional[int] = None) -> CurveResult:
+    """Train the p_miss lane axis through the simulated channel, per bits.
+
+    ``ccfg=None`` runs the default :class:`CurveConfig` grid.  ONE dispatch
+    per ``bits`` value trains every lane and the ideal lane from one init
+    on one batch stream.  ``n_devices`` shards the lanes over local
+    devices (``None``: all of them; ``1``: the single-device vmap path).
+    """
+    ccfg = ccfg if ccfg is not None else CurveConfig()
+    noisy, ideal = zip(*_run(ccfg, _plain_job(ccfg, n_devices)))
+    return CurveResult(
+        config=ccfg, p_miss=ccfg.lane_p_miss(),
+        acc=_fetch(noisy, 2), nll=_fetch(noisy, 3),
+        acc_ideal=_fetch(ideal, 2)[:, 0], nll_ideal=_fetch(ideal, 3)[:, 0],
+        loss_history=_fetch(noisy, 1, lanes_last=True),
+        ideal_loss_history=_fetch(ideal, 1)[:, 0],
+        logged_steps=np.asarray(ccfg.logged_steps()),
+        noisy_params=[o[0] for o in noisy],
+        ideal_params=[o[0] for o in ideal])
+
+
+def run_fault_curves(ccfg: CurveConfig, fault_lanes: Sequence
+                     ) -> FaultCurveResult:
+    """Train a grid of channel-fault lanes through the fused engine.
+
+    ``fault_lanes``: ``repro.faults.FaultModel`` values sharing one
+    ``DegradePolicy`` (static metadata, so mixed policies are rejected);
+    their parameters are traced leaves, ONE dispatch per ``bits`` value.
+    With ``len(fault_lanes) == len(ccfg.p_miss)`` a ``FaultModel.iid(p)``
+    lane trains bit-for-bit the ``run_curves`` lane of the same ``p``.
+    Runs on one device.
+    """
+    outs = _run(ccfg, _fault_job(ccfg, fault_lanes))
+    return FaultCurveResult(
+        config=ccfg, fault_lanes=tuple(fault_lanes),
+        acc=_fetch(outs, 6), nll=_fetch(outs, 7),
+        loss_history=_fetch(outs, 1, lanes_last=True),
+        stale_age=_fetch(outs, 2, np.int64, lanes_last=True),
+        dropped_frames=_fetch(outs, 3, np.int64),
+        outage_frames=_fetch(outs, 4, np.int64),
+        retry_slots=_fetch(outs, 5, np.int64),
+        logged_steps=np.asarray(ccfg.logged_steps()),
+        params=[o[0] for o in outs])
+
+
+def run_scheduled_curves(ccfg: CurveConfig, schedule: BitsSchedule
+                         ) -> ScheduledCurveResult:
+    """Train the ``p_miss`` lanes with a channel-aware ``BitsSchedule``.
+
+    ``schedule.update`` re-chooses the depth every round from the last
+    round's accounting, on device: the whole run is ONE dispatch.  Streams
+    derive from ``candidates[init_index]``, so ``FixedBits(b)`` trains
+    bit-for-bit the ``run_curves(bits=(b,))`` lanes.  Runs on one device.
+    """
+    [(vals, hist, coll, idx_seq, acc, nll)] = _run(
+        ccfg, _sched_job(ccfg, schedule))
+    return ScheduledCurveResult(
+        config=ccfg, schedule=schedule, p_miss=ccfg.lane_p_miss(),
+        acc=np.asarray(acc, np.float64), nll=np.asarray(nll, np.float64),
+        loss_history=np.asarray(hist, np.float64).T,
+        collision_frac=np.asarray(coll, np.float64),
+        bits_per_step=np.asarray(schedule.candidates,
+                                 np.int64)[np.asarray(idx_seq)],
+        logged_steps=np.asarray(ccfg.logged_steps()), params=vals)
 
 
 def run_curves_dp(ccfg: CurveConfig, compress: CompressedAllReduce, *,
                   n_devices: Optional[int] = None) -> DPCurveResult:
     """Train the 2-D (p_miss lanes x DP shards) grid with compressed comms.
 
-    Each lane's training step splits the shared batch stream across
-    ``ccfg.dp_shards`` data-parallel ranks; every rank sparsifies its
-    gradients (top-k + error feedback, per-rank EF memory) and the sparse
-    trees all-reduce via ``compress.reduce`` *inside* the fused scan — the
-    whole run stays ONE host dispatch per ``bits`` value
-    (``dispatch_counts()["fused_dp"]``), with the measured DP payload bits
-    accumulated on device alongside the loss history.
-
-    Placement follows :func:`repro.sim.shard.dp_mesh_shape`: the DP axis
-    lands entirely on the device mesh (when ``dp_shards`` divides into the
-    available devices) or entirely on a named vmap axis, never split —
-    results are bit-for-bit identical across ``n_devices`` (the
-    forced-multi-device subprocess test in ``tests/test_dp_curves.py``).
-
-    Feed the result to ``repro.sim.results.summarize_dp_curves`` for the
-    unified uplink + DP all-reduce communication report.
+    Each step splits the batch across ``ccfg.dp_shards`` ranks whose top-k
+    gradients (per-rank error feedback) ``compress.reduce`` all-reduces in
+    the scan: ONE dispatch per ``bits`` value.  The DP axis lies wholly on
+    the device mesh or on a named vmap axis (``shard.dp_mesh_shape``).
+    ``repro.sim.results.summarize_dp_curves`` reports uplink + DP bits.
     """
-    return _run_curves_dp(ccfg, compress, n_devices)
+    outs = _run(ccfg, _dp_job(ccfg, compress, n_devices))
+    # the analytic per-step bill every measured step must equal
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[1:],
+                                                         x.dtype), outs[-1][0])
+    return DPCurveResult(
+        config=ccfg, compress=compress, p_miss=ccfg.lane_p_miss(),
+        acc=_fetch(outs, 4), nll=_fetch(outs, 5),
+        loss_history=_fetch(outs, 1, lanes_last=True),
+        dp_payload_bits=_fetch(outs, 2, np.int64, lanes_last=True),
+        dp_payload_bits_total=_fetch(outs, 3, np.int64),
+        dp_payload_bits_step=int(compress.payload_bits(params)
+                                 * ccfg.dp_shards),
+        dp_dense_bits_step=int(compress.dense_bits(params) * ccfg.dp_shards),
+        logged_steps=np.asarray(ccfg.logged_steps()),
+        params=[o[0] for o in outs])

@@ -8,6 +8,9 @@ Contracts under test:
     (the legacy per-step python driver is gone; its parity contract lives
     on as the FixedBits-schedule bitwise equivalence in
     ``tests/test_protocol.py``);
+  * every runner of the one engine (plain, fault, scheduled, DP) traces
+    once and dispatches once per ``bits`` value, and its dispatch takes
+    other lane leaves without another trace;
   * the ``p_miss`` lane axis shards over local devices bit-for-bit
     (forced-host-device subprocess, mirroring the sweep-engine property);
   * the ``p_miss=0`` lane is bit-for-bit the ideal
@@ -30,7 +33,8 @@ import pytest
 from repro.core import vertical
 from repro.core.vertical import VerticalConfig
 from repro.optim import optimizers, schedules
-from repro.protocol import Protocol
+from repro.optim.compressed_allreduce import CompressedAllReduce
+from repro.protocol import CollisionAdaptiveBits, Protocol
 from repro.sim import results as sim_results
 from repro.sim import train_curves as tc
 from repro.train.train_step import make_train_step
@@ -61,6 +65,62 @@ def test_scan_engine_one_dispatch_per_bits_value():
     # the ISSUE bound: <= ceil(steps/log_every) + 2 dispatches per bits
     bound = math.ceil(cfg.steps / cfg.log_every) + 2
     assert disp["fused"] / len(cfg.bits) <= bound
+
+
+def _fault_grid(p):
+    from repro.faults import DegradePolicy, FaultModel
+    return [FaultModel.iid(0.0, policy=DegradePolicy.stale()),
+            FaultModel.burst(burst_len=2.0 + 10 * p, gap_len=8.0,
+                             p_miss_bad=0.5, p_miss_good=p,
+                             policy=DegradePolicy.stale())]
+
+
+def _p_rebind(lane, p):
+    return {"p": jnp.asarray([0.0, p], jnp.float32)}
+
+
+def _fault_rebind(lane, p):
+    return {"fm": jax.tree.map(lambda *xs: jnp.stack(xs), *_fault_grid(p))}
+
+
+_SCHED = CollisionAdaptiveBits((8, 16), escalate=0.01)
+_CAR = CompressedAllReduce.topk(1 / 8)
+_RUNNERS = {   # public runner, its job, the lane leaves a rebind changes
+    "fused": (lambda c: tc.run_curves(c, n_devices=1),
+              lambda c: tc._plain_job(c, 1), _p_rebind),
+    "fused_faults": (lambda c: tc.run_fault_curves(c, _fault_grid(0.05)),
+                     lambda c: tc._fault_job(c, _fault_grid(0.05)),
+                     _fault_rebind),
+    "sched": (lambda c: tc.run_scheduled_curves(c, _SCHED),
+              lambda c: tc._sched_job(c, _SCHED), _p_rebind),
+    "fused_dp": (lambda c: tc.run_curves_dp(c, _CAR, n_devices=1),
+                 lambda c: tc._dp_job(c, _CAR, 1), _p_rebind),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_RUNNERS))
+def test_runner_traces_once_and_rebinds_lane_leaves(name):
+    """Each runner traces its engine once and dispatches once per bits
+    value (the scheduled run: once in all), and its dispatch takes other
+    p_miss or fault leaves without another trace."""
+    run, job, rebind = _RUNNERS[name]
+    cfg = dataclasses.replace(TINY, bits=(8, 16), steps=4, log_every=2,
+                              dp_shards=2)
+    n = 1 if name == "sched" else len(cfg.bits)
+    want = {k: n if k == name else 0 for k in tc.trace_counts()}
+    tc.reset_trace_counts()
+    tc.reset_dispatch_counts()
+    run(cfg)
+    assert tc.trace_counts() == want
+    assert tc.dispatch_counts() == want
+
+    fused, params0, opt0, lane, shared = next(
+        tc._dispatches(cfg, job(cfg)))
+    tc.reset_trace_counts()
+    for p in (0.1, 0.2):
+        jax.block_until_ready(fused(
+            params0, opt0, *{**lane, **rebind(lane, p)}.values(), *shared))
+    assert tc.trace_counts()[name] == 1
 
 
 def test_heterogeneous_lane_grid_trains_deterministically():
